@@ -30,12 +30,13 @@ from repro.checkpoint.store import TieredStore
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.virtualization import fetch_tree, place_tree
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.mesh import make_mesh
 
 shape, out, mode = eval(sys.argv[1]), sys.argv[2], sys.argv[3]
 axes = ("data", "model") if len(shape) == 2 else ("pod", "data", "model")
 cfg = reduced(get_config("llama3.2-1b"))
 oc = adamw.OptConfig(warmup_steps=2, decay_steps=20)
-mesh = jax.make_mesh(shape, axes)
+mesh = make_mesh(shape, axes)
 rules = Rules(mesh)
 step_fn, *_ = TS.make_train_step(cfg, mesh, oc, rules=rules, donate=False)
 mgr = CheckpointManager(TieredStore(Path(out)))

@@ -50,7 +50,7 @@ def main():
         sim = SlurmSim(Path(d) / "slurm")
         jid = sim.submit(JobSpec(
             name="pretrain", cmd=cmd, walltime_s=walltime, signal_margin_s=4.0,
-            env={"PYTHONPATH": str(ROOT / "src"), "JAX_PLATFORMS": "cpu"},
+            env={"PYTHONPATH": str(ROOT / "src")},
             max_requeues=50))
         print(f"submitted job {jid} (walltime {walltime}s/attempt) — running...")
         sim.run(timeout_s=86400)

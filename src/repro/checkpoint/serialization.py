@@ -150,8 +150,11 @@ def _inflate_chunk(codec: int, payload: bytes, raw_nbytes: int) -> bytes:
         if _zstd is None:
             raise ChecksumError(
                 "chunk framed with zstd but no zstd binding is available")
-        return _zstd.ZstdDecompressor().decompress(
-            payload, max_output_size=raw_nbytes)
+        try:
+            return _zstd.ZstdDecompressor().decompress(
+                payload, max_output_size=raw_nbytes)
+        except _zstd.ZstdError as e:
+            raise ChecksumError(f"corrupt zstd chunk frame: {e}") from e
     raise ChecksumError(f"unknown chunk codec {codec}")
 
 

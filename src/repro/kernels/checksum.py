@@ -5,9 +5,17 @@ in HBM, and hashing it *before* the device->host transfer detects corruption at
 HBM bandwidth instead of PCIe bandwidth (and lets the coordinator compare
 per-worker digests without moving data).  The hash is an order-dependent
 FNV-style mix (matching kernels/ref.py::checksum exactly): each 32-bit word is
-mixed with its global index, then XOR- and SUM-reduced.  Both reductions are
-associative, so per-block partials combine across sequential grid steps in
-SMEM scratch.
+mixed with its index, then XOR- and SUM-reduced.
+
+Layout, shared by both kernels: a block of ``block`` words is one grid step,
+laid out lane-dense as ``(block // L, L)`` with ``L = min(block, 128)``.  The
+kernel mixes the block and folds its rows in halves (aligned to the 8-row
+sublane tile) down to at most 8 rows, for XOR and for SUM separately, and
+writes both partials.  XLA finishes the reduction over each block's (or the
+whole stream's) partials.  Both reductions are associative and commutative,
+so the digest is bit-identical to the oracle's.  The kernel computes in
+int32: Mosaic reduces no unsigned type, and xor, multiply and wrapping add
+give the same bits in either signedness.
 """
 from __future__ import annotations
 
@@ -16,45 +24,63 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 PRIME = 16777619
+_LANES = 128
+_FOLD_ROWS = 8
 
 
 def require_pow2(value: int, name: str = "block") -> None:
-    """Both kernels fold their XOR reduction with a reshape-halving tree, so
-    the tile length must be a positive power of two — anything else would
-    silently drop words.  Raised eagerly (host-side), mirrored by
-    kernels/ops.py so every impl fails the same way."""
+    """Both kernels fold their reductions in halves, so the tile length must
+    be a positive power of two — anything else would silently drop words.
+    Raised eagerly (host-side), mirrored by kernels/ops.py so every impl
+    fails the same way."""
     if value < 1 or value & (value - 1):
         raise ValueError(f"{name} must be a positive power of two, got {value}")
 
 
-def _checksum_kernel(w_ref, o_ref, xacc_ref, sacc_ref, *, nb, block):
-    bi = pl.program_id(0)
+def _fold_rows(x, op):
+    rows = x.shape[0]
+    while rows > _FOLD_ROWS:
+        rows //= 2
+        x = op(x[:rows], x[rows:])
+    return x
 
-    @pl.when(bi == 0)
-    def _init():
-        xacc_ref[0] = jnp.uint32(0)
-        sacc_ref[0] = jnp.uint32(0)
 
-    w = w_ref[...]
-    idx = (bi * block + jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
-           ).astype(jnp.uint32)
-    mixed = (w ^ (idx * jnp.uint32(PRIME))) * (idx | jnp.uint32(1))
-    # XOR-reduce via bit tricks: jnp.bitwise_xor.reduce is not available in
-    # kernels; fold with a log-tree using reshape halving.
-    x = mixed
-    n = block
-    while n > 1:
-        x = x[: n // 2] ^ x[n // 2 :]
-        n //= 2
-    xacc_ref[0] = xacc_ref[0] ^ x[0]
-    sacc_ref[0] = sacc_ref[0] + jnp.sum(mixed, dtype=jnp.uint32)
+def _fold_kernel(w_ref, x_ref, s_ref, *, global_index):
+    w = w_ref[0]
+    rows, lanes = w.shape
+    idx = (jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 0) * lanes
+           + jax.lax.broadcasted_iota(jnp.int32, (rows, lanes), 1))
+    if global_index:
+        idx = idx + pl.program_id(0) * (rows * lanes)
+    mixed = (w ^ (idx * PRIME)) * (idx | 1)
+    x_ref[0] = _fold_rows(mixed, jnp.bitwise_xor)
+    s_ref[0] = _fold_rows(mixed, jnp.add)
 
-    @pl.when(bi == nb - 1)
-    def _final():
-        o_ref[0] = xacc_ref[0] + sacc_ref[0]
+
+def _block_partials(words: jax.Array, block: int, interpret: bool,
+                    global_index: bool):
+    """(N,) uint32 with N % block == 0 -> per-block XOR and SUM partials,
+    each (N // block, k) uint32.  ``global_index`` mixes each word with its
+    position in the stream; otherwise with its position in its block."""
+    lanes = min(block, _LANES)
+    rows = block // lanes
+    nb = words.shape[0] // block
+    fold = min(rows, _FOLD_ROWS)
+    w = jax.lax.bitcast_convert_type(words, jnp.int32).reshape(nb, rows, lanes)
+    part = jax.ShapeDtypeStruct((nb, fold, lanes), jnp.int32)
+    xp, sp = pl.pallas_call(
+        functools.partial(_fold_kernel, global_index=global_index),
+        grid=(nb,),
+        in_specs=[pl.BlockSpec((1, rows, lanes), lambda i: (i, 0, 0))],
+        out_specs=[pl.BlockSpec((1, fold, lanes), lambda i: (i, 0, 0))] * 2,
+        out_shape=[part, part],
+        interpret=interpret,
+    )(w)
+    as_u32 = functools.partial(jax.lax.bitcast_convert_type,
+                               new_dtype=jnp.uint32)
+    return as_u32(xp).reshape(nb, -1), as_u32(sp).reshape(nb, -1)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -64,62 +90,25 @@ def checksum_pallas(words: jax.Array, *, block: int = 2048,
     require_pow2(block)
     n = words.shape[0]
     if n == 0:
-        # the ref oracle's empty digest: XOR and SUM over nothing are both 0.
-        # Without this guard the block math below degenerates through
-        # (-1).bit_length() == 0 into a zero-step grid with an uninitialized
-        # SMEM output.
+        # the ref oracle's empty digest: XOR and SUM over nothing are both 0
         return jnp.uint32(0)
     block = min(block, max(8, 1 << (n - 1).bit_length()))
     pad = (-n) % block
     if pad:
-        # zero words at index >= n change the digest; mix is index-dependent, so
-        # pad with zeros AND account: zero word mixes to (0 ^ idx*P)*(idx|1) !=0.
-        # Instead pad the *input* and compute on the padded length — the ref
-        # oracle is called on the same padded array by the ops wrapper.
+        # the mix is index-dependent, so zero padding changes the digest: the
+        # digest is defined on the padded stream, and the ops wrapper hands
+        # the ref oracle the same padded array
         words = jnp.pad(words, (0, pad))
-        n = words.shape[0]
-    nb = n // block
-    kernel = functools.partial(_checksum_kernel, nb=nb, block=block)
-    return pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
-        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((1,), jnp.uint32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.uint32), pltpu.SMEM((1,), jnp.uint32)],
-        interpret=interpret,
-    )(words)[0]
-
-
-def _chunk_fp_kernel(w_ref, o_ref, *, chunk_words):
-    # one grid step = one chunk; index mixing is chunk-LOCAL so the value
-    # matches serialization.fingerprint_chunks / ref.chunk_fingerprints on
-    # the same word stream whatever the chunk's position in the leaf
-    w = w_ref[...]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (chunk_words,), 0).astype(jnp.uint32)
-    mixed = (w ^ (idx * jnp.uint32(PRIME))) * (idx | jnp.uint32(1))
-    x = mixed
-    n = chunk_words
-    while n > 1:
-        x = x[: n // 2] ^ x[n // 2 :]
-        n //= 2
-    o_ref[0] = x[0] + jnp.sum(mixed, dtype=jnp.uint32)
+    xp, sp = _block_partials(words, block, interpret, global_index=True)
+    return jnp.bitwise_xor.reduce(xp, axis=None) + jnp.sum(sp, dtype=jnp.uint32)
 
 
 def _chunk_fp_call(words: jax.Array, chunk_words: int,
                    interpret: bool) -> jax.Array:
-    """pallas_call over an ALIGNED word stream (len % chunk_words == 0)."""
-    nc = words.shape[0] // chunk_words
-    kernel = functools.partial(_chunk_fp_kernel, chunk_words=chunk_words)
-    return pl.pallas_call(
-        kernel,
-        grid=(nc,),
-        in_specs=[pl.BlockSpec((chunk_words,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,),
-                               memory_space=pltpu.SMEM),
-        out_shape=jax.ShapeDtypeStruct((nc,), jnp.uint32),
-        interpret=interpret,
-    )(words)
+    """Fingerprints of an ALIGNED word stream (len % chunk_words == 0)."""
+    xp, sp = _block_partials(words, chunk_words, interpret, global_index=False)
+    return jnp.bitwise_xor.reduce(xp, axis=1) + jnp.sum(sp, axis=1,
+                                                        dtype=jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk_words", "interpret"))
@@ -134,10 +123,10 @@ def chunk_fingerprints_pallas(words: jax.Array, *, chunk_words: int,
     zero-padded — same convention as every other impl, so the three agree
     bit-for-bit.  The pad touches ONLY the tail chunk (body and padded tail
     go through separate grids), so fingerprinting a big device-resident
-    leaf never materializes an O(leaf) padded copy in HBM.  Same tiling
-    idiom as ``checksum_pallas``: a 1-d grid over blocks with the per-chunk
-    digest landing in SMEM; no scratch, since chunks don't combine across
-    grid steps.
+    leaf never materializes an O(leaf) padded copy in HBM.  One grid step
+    per chunk; index mixing is chunk-LOCAL, so the value matches
+    serialization.fingerprint_chunks / ref.chunk_fingerprints whatever the
+    chunk's position in the leaf.
     """
     require_pow2(chunk_words, name="chunk_words")
     n = words.shape[0]

@@ -25,15 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# jax >= 0.6 promotes shard_map to jax.shard_map and renames check_rep ->
-# check_vma; older jax ships it under experimental
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 
 def _block_attend(q, k, v, q_off, k_off, scale, causal):
     """One masked flash block in fp32.  q: (B,Sq,Hkv,G,D) k/v: (B,Sk,Hkv,D)."""
@@ -110,9 +101,9 @@ def ring_attention(q, k, v, *, mesh, axis: str = "model", scale=None,
     spec_q = P(bspec, axis, None, None)
     fn = functools.partial(ring_attention_local, axis_name=axis, scale=scale,
                            causal=causal, axis_size=int(mesh.shape[axis]))
-    return _shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(spec_q, spec_q, spec_q),
         out_specs=spec_q,
-        **{_CHECK_KW: False},
+        check_vma=False,
     )(q, k, v)

@@ -80,7 +80,7 @@ def wkv6_step(r, k, v, w, u, state):
 
 
 def wkv6_chunked(r, k, v, w, u, *, chunk=128, init_state=None, return_state=False,
-                 interpret=True):
+                 interpret=False):
     from repro.kernels._rwkv6_pallas import wkv6_pallas
 
     return wkv6_pallas(r, k, v, w, u, chunk=chunk, init_state=init_state,

@@ -81,7 +81,7 @@ def ssd_step(x, dt, A_log, Bm, Cm, D, state):
 
 
 def ssd_chunked(x, dt, A_log, Bm, Cm, D, *, chunk=256, init_state=None,
-                return_state=False, interpret=True):
+                return_state=False, interpret=False):
     """Pallas TPU kernel wrapper (defined in this module, kernel body below)."""
     from repro.kernels._ssd_pallas import ssd_pallas
 

@@ -34,6 +34,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager, CheckpointPolicy
 from repro.checkpoint.store import TieredStore, node_local_tier_roots
 from repro.configs.base import get_config, reduced as reduce_cfg
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.sched.cache_registry import REGISTRY_DIRNAME, CacheRegistry
@@ -160,6 +161,7 @@ def main(argv=None) -> int:
                     help="--follow: expect delta (chunked) weight pushes")
     ap.add_argument("--restore-workers", type=int, default=0)
     args = ap.parse_args(argv)
+    configure_compile_cache()
     if args.follow:
         return follow(args)
 

@@ -20,6 +20,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import signal
 import sys
 import time
 from pathlib import Path
@@ -36,6 +37,7 @@ from repro.sched.cache_registry import (ENV_PEER_ROOTS, REGISTRY_DIRNAME,
 from repro.core.signals import SignalTrap
 from repro.core.worker import CkptClient, InlineCoordinator
 from repro.data.pipeline import PipelineState, SyntheticTokens
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.optim import adamw
 from repro.parallel.mesh_rules import Rules
@@ -140,6 +142,24 @@ def build_argparser():
     return ap
 
 
+def run_summary(state_ready_s: float, metrics_log: list, events: list) -> dict:
+    """One attempt's device and timings: ``state_ready_s`` (restore or
+    init, placed on device), ``first_step_s`` (compile included),
+    ``step_s`` (the later steps' median), ``save_s`` per committed save,
+    and the device's peak memory where the backend reports it."""
+    dev = jax.devices()[0]
+    steps = sorted(m["step_s"] for m in metrics_log[1:])
+    return {
+        "platform": dev.platform, "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
+        "state_ready_s": state_ready_s,
+        "first_step_s": metrics_log[0]["step_s"] if metrics_log else None,
+        "step_s": steps[len(steps) // 2] if steps else None,
+        "save_s": [e["duration_s"] for e in events if "duration_s" in e],
+        "peak_bytes_in_use": (dev.memory_stats() or {}).get("peak_bytes_in_use"),
+    }
+
+
 def main(argv=None) -> int:
     args = build_argparser().parse_args(argv)
     if args.ckpt_delta and args.ckpt_incremental:
@@ -154,6 +174,7 @@ def main(argv=None) -> int:
     # to the C/R loop itself.
     trap = SignalTrap()
     trap.__enter__()
+    configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -234,7 +255,10 @@ def main(argv=None) -> int:
         # template for restore: abstract state (host arrays will be placed in)
         templates = {"state": TS.abstract_train_state(cfg, oc)}
         axes = {"state": TS.state_logical_axes(cfg)}
+        t0 = time.perf_counter()
         state, meta, start_step = crm.restore_or_init(init_fn, templates, axes)
+        jax.block_until_ready(state)
+        state_ready_s = time.perf_counter() - t0
         if meta is not None and "data_state" in meta:
             pipe.restore(PipelineState.from_dict(meta["data_state"]))
 
@@ -243,12 +267,14 @@ def main(argv=None) -> int:
         step = start_step
         for step in range(start_step, args.steps):
             batch = next(pipe)
+            t0 = time.perf_counter()
             state, metrics = jitted(state, batch)
+            loss = float(metrics["loss"])       # waits for the step
+            step_s = time.perf_counter() - t0
             if args.step_sleep:
                 time.sleep(args.step_sleep)
-            loss = float(metrics["loss"])
             metrics_log.append({"step": step, "loss": loss,
-                                "t": time.time()})
+                                "t": time.time(), "step_s": step_s})
             if step % 10 == 0 or step == args.steps - 1:
                 print(f"step {step} loss {loss:.4f}", flush=True)
 
@@ -269,10 +295,18 @@ def main(argv=None) -> int:
         if args.metrics_out:
             Path(args.metrics_out).write_text(json.dumps(metrics_log))
         crm.close()
+        print("[train] summary " + json.dumps(
+            run_summary(state_ready_s, metrics_log, crm.events)), flush=True)
     finally:
         trap.__exit__(None, None, None)
     return exit_code
 
 
 if __name__ == "__main__":
+    # main() traps these signals while it runs and then puts back what it
+    # found.  Finding them ignored, it leaves them ignored while the finished
+    # process frees its state and exits: a walltime warning landing then has
+    # nothing left to save and must not kill a completed job.
+    for sig in SignalTrap().signals:
+        signal.signal(sig, signal.SIG_IGN)
     sys.exit(main())

@@ -141,7 +141,7 @@ def make_train_step(cfg: ModelConfig, mesh, oc: adamw.OptConfig, *,
         out_metrics = {"loss": loss, "ce": metrics.get("ce", loss), **om}
         return new_state, out_metrics
 
-    def wrapped(state, batch):
+    def sharded_train_step(state, batch):
         with use_shard_resolver(resolver), use_mesh_context(mesh, rules):
             return train_step(state, batch)
 
@@ -154,7 +154,7 @@ def make_train_step(cfg: ModelConfig, mesh, oc: adamw.OptConfig, *,
         }
 
     jitted = jax.jit(
-        wrapped,
+        sharded_train_step,
         donate_argnums=(0,) if donate else (),
         out_shardings=(st_sh, None),
     )

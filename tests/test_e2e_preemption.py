@@ -3,6 +3,7 @@ simulator is preempted (walltime USR1), checkpoints, exits 85, is requeued, and
 finishes with params BIT-IDENTICAL to an uninterrupted reference run."""
 import json
 import os
+import signal
 import subprocess
 import sys
 from pathlib import Path
@@ -29,15 +30,18 @@ def test_preempt_requeue_bit_identical(tmp_path):
 
     ref_dir, pre_dir = tmp_path / "ref", tmp_path / "pre"
     ref_metrics, pre_metrics = tmp_path / "ref.json", tmp_path / "pre.json"
+    # 80 steps sleep 16 s: the first attempt outlasts the 17 s warning even
+    # when it loads its step from the persistent compile cache
+    steps = 80
 
-    r = subprocess.run(_base_cmd(ref_dir, ref_metrics), env=env,
+    r = subprocess.run(_base_cmd(ref_dir, ref_metrics, steps), env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stdout + r.stderr
 
     sim = SlurmSim(tmp_path / "sim")
     jid = sim.submit(JobSpec(
         name="train", walltime_s=20.0, signal_margin_s=3.0,
-        cmd=_base_cmd(pre_dir, pre_metrics), env={"PYTHONPATH": SRC,
+        cmd=_base_cmd(pre_dir, pre_metrics, steps), env={"PYTHONPATH": SRC,
                                                   "JAX_PLATFORMS": "cpu"},
         max_requeues=10))
     sim.run(timeout_s=400)
@@ -51,6 +55,21 @@ def test_preempt_requeue_bit_identical(tmp_path):
     last = max(ref)
     assert last in pre, "requeued job never reached the final step"
     assert ref[last] == pre[last], "preempted run diverged from reference"
+
+
+def test_warning_signal_during_shutdown_keeps_exit_zero(tmp_path):
+    """A walltime warning that lands while a finished run shuts down has
+    nothing left to checkpoint: the job still exits 0, not by the signal."""
+    proc = subprocess.Popen(
+        _base_cmd(tmp_path / "ck", tmp_path / "m.json", steps=2),
+        env={**os.environ, "PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu"},
+        stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+    out = []
+    for line in proc.stdout:
+        out.append(line)
+        if line.startswith("[train] summary"):
+            proc.send_signal(signal.SIGUSR1)
+    assert proc.wait(timeout=120) == 0, "".join(out)
 
 
 @pytest.mark.slow

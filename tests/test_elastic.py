@@ -24,11 +24,12 @@ from repro.checkpoint.store import TieredStore
 from repro.checkpoint.manager import CheckpointManager
 from repro.core.virtualization import fetch_tree, place_tree
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.mesh import make_mesh
 
 mesh_shape = eval(sys.argv[1]); out = sys.argv[2]; mode = sys.argv[3]
 cfg = reduced(get_config("llama3.2-1b"))
 oc = adamw.OptConfig(warmup_steps=2, decay_steps=10)
-mesh = jax.make_mesh(mesh_shape, ("data", "model")[:len(mesh_shape)] if len(mesh_shape)==2 else ("pod","data","model"))
+mesh = make_mesh(mesh_shape, ("data", "model")[:len(mesh_shape)] if len(mesh_shape)==2 else ("pod","data","model"))
 rules = Rules(mesh)
 step_fn, st_sh, bsf = TS.make_train_step(cfg, mesh, oc, rules=rules, donate=False)
 store = TieredStore(Path(out))

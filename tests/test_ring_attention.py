@@ -14,8 +14,9 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.kernels import ref
 from repro.kernels.ring_attention import ring_attention
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 rng = np.random.default_rng(0)
 for (B, S, H, Hkv, D) in [(2, 64, 4, 2, 32), (4, 128, 14, 2, 16), (2, 64, 4, 4, 64)]:
     q = jnp.asarray(rng.standard_normal((B, S, H, D), np.float32))

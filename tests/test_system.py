@@ -2,8 +2,10 @@
 surface (cold start, interval checkpoints, restore, async mode, incremental),
 exercised through the public CLI in-process."""
 import json
+from pathlib import Path
 
 import numpy as np
+import pytest
 
 from repro.launch import train as T
 from repro.sched.slurmsim import REQUEUE_EXIT
@@ -78,3 +80,27 @@ def test_loss_goes_down_on_learnable_data():
         state, metrics = jitted(state, batch)
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0] - 1.0, (losses[0], losses[-1])
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "checkout"])
+def test_compile_cache_has_one_fixed_path(tmp_path, monkeypatch, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR when set, else <checkout>/.jax_cache:
+    never a path that moves between restarts."""
+    import jax
+
+    from repro.launch import compile_cache as CC
+
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        want = str(tmp_path)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CC.CHECKOUT_CACHE_DIR)
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        assert CC.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert CC.CHECKOUT_CACHE_DIR == (
+        Path(__file__).resolve().parents[1] / ".jax_cache")
