@@ -33,6 +33,8 @@ import threading
 import traceback
 from typing import Callable, Optional
 
+from repro.utils import tracing as TR
+
 
 class WorkPool:
     """Bounded-in-flight daemon thread pool.
@@ -42,6 +44,8 @@ class WorkPool:
     task exception is re-raised on the producer thread at the next
     ``submit``/``wait`` (tasks after a failure still run — each task must be
     independently meaningful, which checkpoint writes and promotions are).
+    While the tracer is on, each task is a ``repro.pool.task`` span whose
+    parent is the span that submitted it.
     """
 
     def __init__(self, max_inflight: int = 3, workers: int = 1,
@@ -55,6 +59,7 @@ class WorkPool:
         self._inflight = 0
         self._done = threading.Condition()
         self._closed = False
+        self.name = name
         self._threads = [
             threading.Thread(target=self._run, daemon=True, name=f"{name}-{i}")
             for i in range(workers)
@@ -64,11 +69,13 @@ class WorkPool:
 
     def _run(self):
         while True:
-            fn = self._q.get()
-            if fn is None:
+            item = self._q.get()
+            if item is None:
                 return
+            fn, parent = item
             try:
-                fn()
+                with TR.span("repro.pool.task", parent=parent, pool=self.name):
+                    fn()
             except BaseException as e:  # noqa: BLE001 — surfaced on wait()
                 with self._lock:
                     self._err = e
@@ -85,7 +92,7 @@ class WorkPool:
         with self._done:
             self._done.wait_for(lambda: self._inflight < self._max_inflight)
             self._inflight += 1
-        self._q.put(fn)
+        self._q.put((fn, TR.current()))
 
     def try_submit(self, fn: Callable[[], None]) -> bool:
         """Non-blocking submit: False when the in-flight bound is reached.
@@ -99,7 +106,7 @@ class WorkPool:
             if self._inflight >= self._max_inflight:
                 return False
             self._inflight += 1
-        self._q.put(fn)
+        self._q.put((fn, TR.current()))
         return True
 
     def wait(self, timeout: Optional[float] = None) -> None:

@@ -146,6 +146,28 @@ def _group_by_file(requests):
     return [(paths[k], v) for k, v in groups.items()]
 
 
+def preadv_full(fd: int, bufs: list, pos: int) -> int:
+    """``os.preadv`` until every buffer is full or the file ends; returns
+    the bytes read.  A read may return fewer bytes than asked without being
+    at the end of the file (POSIX allows it; network file systems do it)."""
+    views = [memoryview(b) for b in bufs]
+    total = 0
+    try:
+        while views:
+            got = os.preadv(fd, views, pos + total)
+            if got == 0:
+                break
+            total += got
+            while views and got >= views[0].nbytes:
+                got -= views.pop(0).nbytes
+            if got:
+                views[0] = views[0][got:]
+    finally:
+        for v in views:
+            v.release()
+    return total
+
+
 def _drain_preadv(fd: int, reqs: list, results: list) -> None:
     """One (or a few, IOV_MAX-capped) vectored submissions for all ranges of
     one file.  Short reads surface as OSError in that range's slot only."""
@@ -163,7 +185,7 @@ def _drain_preadv(fd: int, reqs: list, results: list) -> None:
             while (k < len(window) and window[k][1] == end):
                 end += window[k][2]
                 k += 1
-            got = os.preadv(fd, bufs[j:k], pos)
+            got = preadv_full(fd, bufs[j:k], pos)
             want = end - pos
             if got != want:
                 # a short vectored read torn across ranges: mark each range
@@ -200,17 +222,17 @@ def _drain_direct(path: Path, reqs: list, results: list,
             span = hi - lo
             # mmap length must be page-rounded; the extra tail is unused
             buf = mmap.mmap(-1, -(-span // _PAGE) * _PAGE)
+            view = memoryview(buf)[:span]
             try:
-                view = memoryview(buf)[:span]
-                got = os.preadv(fd, [view], lo)
+                got = preadv_full(fd, [view], lo)
                 # bytes of the REQUESTED range that actually arrived: the
                 # aligned read starts at lo, so the first (offset - lo)
                 # bytes are alignment padding, and EOF clamps the tail
                 avail = max(0, min(got, size - lo) - (offset - lo))
                 take = min(nbytes, avail)
                 results[i] = bytes(view[offset - lo:offset - lo + take])
-                del view
             finally:
+                view.release()
                 buf.close()
     finally:
         os.close(fd)

@@ -52,6 +52,7 @@ from repro.checkpoint.policy import PROMOTE_POLICIES, CheckpointPolicy
 from repro.checkpoint.restore_engine import ParallelRestorer
 from repro.checkpoint.store import (TieredStore, chunk_refcounts, chunk_rel,
                                     manifest_chunk_hashes)
+from repro.utils import tracing as TR
 
 __all__ = ["CheckpointManager", "CheckpointPolicy", "PROMOTE_POLICIES"]
 
@@ -335,16 +336,20 @@ class CheckpointManager:
         view, and serves as both the incremental diff key and the stored shard
         checksum — see the ``diff`` comment below for where it is computed.
         """
+        with TR.span("repro.ckpt.save", timed=True, cpu=True, step=step) as sp:
+            return self._save(step, tree, extra_meta, sp)
+
+    def _save(self, step: int, tree, extra_meta: Optional[dict], sp) -> dict:
         if self.delta and self.device_fp:
             # device-resident dirty detection: NO full snapshot — the
             # fingerprint pass runs on the live tree and only fp-dirty
             # chunk ranges are device_get'd
-            return self._save_delta_device(step, tree, extra_meta)
-        t0 = time.time()
-        records = SER.tree_to_records(tree)            # snapshot (device_get)
-        snap_s = time.time() - t0
+            return self._save_delta_device(step, tree, extra_meta, sp)
+        with TR.span("repro.ckpt.save.snapshot", timed=True) as snap:
+            records = SER.tree_to_records(tree)        # snapshot (device_get)
+        snap_s = snap.elapsed_s
         if self.delta:
-            return self._save_delta(step, records, snap_s, extra_meta)
+            return self._save_delta(step, records, snap_s, extra_meta, sp)
         mine = self._my_leaves(records)
         sdir = _step_dir(self.prefix, step)
         shard_rel = f"{sdir}/shard_w{self.worker_id:05d}.bin"
@@ -417,9 +422,10 @@ class CheckpointManager:
                     for t in footer["tensors"]:
                         if t["path"] in pending:
                             pending[t["path"]]["crc32"] = t["crc32"]
-            self.store.put(
-                self.tier, f"{sdir}/wpart_{self.worker_id:05d}.json",
-                json.dumps(part).encode(), replicas=self.replicas)
+            with TR.span("repro.ckpt.save.wpart"):
+                self.store.put(
+                    self.tier, f"{sdir}/wpart_{self.worker_id:05d}.json",
+                    json.dumps(part).encode(), replicas=self.replicas)
 
         if self._writer is not None:
             self._writer.submit(do_write)
@@ -492,9 +498,9 @@ class CheckpointManager:
             raise ValueError("precommit requires delta mode")
         if self.device_fp:
             return self._precommit_device(step, tree)
-        t0 = time.time()
-        records = SER.tree_to_records(tree)        # snapshot (device_get)
-        snap_s = time.time() - t0
+        with TR.span("repro.ckpt.predump.snapshot", timed=True) as snap:
+            records = SER.tree_to_records(tree)    # snapshot (device_get)
+        snap_s = snap.elapsed_s
         snap_bytes = sum(np.asarray(a).nbytes for _, a in records)
         mine = self._my_leaves(records)
         parent = self._parent_manifest()
@@ -527,67 +533,67 @@ class CheckpointManager:
                 prev_leaves = {}
             else:
                 prev_leaves = (prev or {}).get("leaves") or {}
-            t1 = time.perf_counter()
-            fps = {name: SER.fingerprint_chunks(
-                       SER.as_byte_view(np.asarray(arr)), self.chunk_bytes)
-                   for _, name, arr in mine}
-            # iterative pre-copy (CRIU): at lead k the PREVIOUS lead's
-            # entries (else the parent manifest's) are the reference — an
-            # fp-clean chunk reuses its hash/CRC outright, so lead N-1
-            # hashes only what dirtied since lead N-2, not the whole tree.
-            # Same 32-bit trust the pre-dump consumption path already
-            # accepts (fps are stamped on every pre-dump entry).
-            known: dict = {}
-            for _, name, _arr in mine:
-                fp = fps[name]
-                if name in prev_leaves:
-                    refs = prev_leaves[name]["entries"]
-                else:
-                    refs = parent_leaves.get(name)
-                if not refs:
-                    continue
-                kmap = {i: e for i, e in enumerate(refs)
-                        if i < len(fp) and e.get("fp") is not None
-                        and int(fp[i]) == int(e["fp"])}
-                if kmap:
-                    known[name] = kmap
-            hashed, hstats = self.hash_engine.chunk_records(
-                [(name, arr) for _, name, arr in mine], self.chunk_bytes,
-                known=known, fps=fps)
-            hash_s = time.perf_counter() - t1
-            t1 = time.perf_counter()
-            written: set = set((prev or {}).get("written") or ())
-            cbytes: dict = dict((prev or {}).get("cbytes") or {})
-            # markers travel with the write set they protect: a superseded
-            # pre-dump's marker stays up until the save that consumes (and
-            # sweeps) the carried chunks finally lands
-            markers = list((prev or {}).get("markers") or ())
-            markers.append(marker_rel)
-            leaves = {}
-            prewritten_n = 0
-            for _, name, _arr in mine:
-                entries, views, leaf_crc = hashed[name]
-                leaves[name] = {"entries": entries, "crc32": leaf_crc}
-                for e, v in zip(entries, views):
-                    h = e["hash"]
-                    if h in parent_hashes or h in written:
+            with TR.span("repro.ckpt.predump.hash", timed=True) as hash_span:
+                fps = {name: SER.fingerprint_chunks(
+                           SER.as_byte_view(np.asarray(arr)), self.chunk_bytes)
+                       for _, name, arr in mine}
+                # iterative pre-copy (CRIU): at lead k the PREVIOUS lead's
+                # entries (else the parent manifest's) are the reference — an
+                # fp-clean chunk reuses its hash/CRC outright, so lead N-1
+                # hashes only what dirtied since lead N-2, not the whole tree.
+                # Same 32-bit trust the pre-dump consumption path already
+                # accepts (fps are stamped on every pre-dump entry).
+                known: dict = {}
+                for _, name, _arr in mine:
+                    fp = fps[name]
+                    if name in prev_leaves:
+                        refs = prev_leaves[name]["entries"]
+                    else:
+                        refs = parent_leaves.get(name)
+                    if not refs:
                         continue
-                    # force=True for the same gc-race reason as the save
-                    # path; the save re-checks existence before trusting a
-                    # pre-written chunk, so a reap between now and then is
-                    # repaired, not served
-                    blob = (SER.frame_chunk(v, self.compress)
-                            if self.compress else v)
-                    self.store.put_chunk(self.tier, self.prefix, h, blob,
-                                         replicas=self.replicas, force=True)
-                    written.add(h)
-                    cbytes[h] = len(blob)
-                    prewritten_n += 1
+                    kmap = {i: e for i, e in enumerate(refs)
+                            if i < len(fp) and e.get("fp") is not None
+                            and int(fp[i]) == int(e["fp"])}
+                    if kmap:
+                        known[name] = kmap
+                hashed, hstats = self.hash_engine.chunk_records(
+                    [(name, arr) for _, name, arr in mine], self.chunk_bytes,
+                    known=known, fps=fps)
+            with TR.span("repro.ckpt.predump.write", timed=True) as write_span:
+                written: set = set((prev or {}).get("written") or ())
+                cbytes: dict = dict((prev or {}).get("cbytes") or {})
+                # markers travel with the write set they protect: a superseded
+                # pre-dump's marker stays up until the save that consumes (and
+                # sweeps) the carried chunks finally lands
+                markers = list((prev or {}).get("markers") or ())
+                markers.append(marker_rel)
+                leaves = {}
+                prewritten_n = 0
+                for _, name, _arr in mine:
+                    entries, views, leaf_crc = hashed[name]
+                    leaves[name] = {"entries": entries, "crc32": leaf_crc}
+                    for e, v in zip(entries, views):
+                        h = e["hash"]
+                        if h in parent_hashes or h in written:
+                            continue
+                        # force=True for the same gc-race reason as the save
+                        # path; the save re-checks existence before trusting a
+                        # pre-written chunk, so a reap between now and then is
+                        # repaired, not served
+                        blob = (SER.frame_chunk(v, self.compress)
+                                if self.compress else v)
+                        self.store.put_chunk(self.tier, self.prefix, h, blob,
+                                             replicas=self.replicas, force=True)
+                        written.add(h)
+                        cbytes[h] = len(blob)
+                        prewritten_n += 1
             self._predump = {
                 "step": step, "chunk_bytes": self.chunk_bytes,
                 "leaves": leaves, "written": written, "markers": markers,
                 "cbytes": cbytes,
-                "hash_s": hash_s, "write_s": time.perf_counter() - t1,
+                "hash_s": hash_span.elapsed_s,
+                "write_s": write_span.elapsed_s,
                 "chunks_hashed": hstats["chunks_hashed"],
                 "chunks_prewritten": prewritten_n,
                 "d2h_bytes": snap_bytes, "d2h_s": snap_s,
@@ -636,39 +642,39 @@ class CheckpointManager:
         the pool as usual.  At lead k the previous lead's entries are the
         fp reference, so iterative pre-dumps each touch only the bytes that
         changed since the one before (CRIU pre-copy)."""
-        t0 = time.time()
-        # drain (don't consume) any running pre-dump so its entries are
-        # readable as this round's reference
-        self.wait_predump()
-        prev = self._predump
-        prev_ok = (prev is not None
-                   and prev.get("chunk_bytes") == self.chunk_bytes)
-        prev_leaves = (prev.get("leaves") or {}) if prev_ok else {}
-        prev_written = (prev.get("written") or set()) if prev_ok else set()
-        from repro.utils.tree import flatten_with_names
+        with TR.span("repro.ckpt.predump.scan", timed=True) as scan:
+            # drain (don't consume) any running pre-dump so its entries are
+            # readable as this round's reference
+            self.wait_predump()
+            prev = self._predump
+            prev_ok = (prev is not None
+                       and prev.get("chunk_bytes") == self.chunk_bytes)
+            prev_leaves = (prev.get("leaves") or {}) if prev_ok else {}
+            prev_written = (prev.get("written") or set()) if prev_ok else set()
+            from repro.utils.tree import flatten_with_names
 
-        named = flatten_with_names(tree)
-        mine = [(i, name, leaf) for i, (name, leaf) in enumerate(named)
-                if i % self.num_workers == self.worker_id]
-        parent = self._parent_manifest()
-        parent_hashes = manifest_chunk_hashes(parent) if parent else set()
-        parent_leaves = {e["path"]: e for e in (parent or {}).get(
-            "leaves", ()) if "chunks" in e}
+            named = flatten_with_names(tree)
+            mine = [(i, name, leaf) for i, (name, leaf) in enumerate(named)
+                    if i % self.num_workers == self.worker_id]
+            parent = self._parent_manifest()
+            parent_hashes = manifest_chunk_hashes(parent) if parent else set()
+            parent_leaves = {e["path"]: e for e in (parent or {}).get(
+                "leaves", ()) if "chunks" in e}
 
-        def refs_for(name):
-            if name in prev_leaves:
-                return prev_leaves[name]["entries"]
-            pl = parent_leaves.get(name)
-            return pl["chunks"] if pl else None
+            def refs_for(name):
+                if name in prev_leaves:
+                    return prev_leaves[name]["entries"]
+                pl = parent_leaves.get(name)
+                return pl["chunks"] if pl else None
 
-        def trust(h):
-            # no existence probe at pre-dump time — the consuming save
-            # re-verifies every pre-written hash before trusting it, so a
-            # reap between now and then is repaired there
-            return h in parent_hashes or h in prev_written
+            def trust(h):
+                # no existence probe at pre-dump time — the consuming save
+                # re-verifies every pre-written hash before trusting it, so a
+                # reap between now and then is repaired there
+                return h in parent_hashes or h in prev_written
 
-        plans, dstats = self._device_scan(mine, refs_for, trust)
-        snap_s = time.time() - t0
+            plans, dstats = self._device_scan(mine, refs_for, trust)
+        snap_s = scan.elapsed_s
 
         def do_predump():
             # marker-first + carry semantics identical to the host pre-dump
@@ -684,34 +690,34 @@ class CheckpointManager:
             cbytes: dict = dict((prev2 or {}).get("cbytes") or {})
             markers = list((prev2 or {}).get("markers") or ())
             markers.append(marker_rel)
-            t1 = time.perf_counter()
-            hashed, hashed_n = self._plans_to_leaves(plans)
-            hash_s = time.perf_counter() - t1
-            t1 = time.perf_counter()
-            leaves = {}
-            prewritten_n = 0
-            for _idx, name, _dtype, _shape, _nbytes, _slots in plans:
-                entries, views, leaf_crc = hashed[name]
-                leaves[name] = {"entries": entries, "crc32": leaf_crc}
-                for e, v in zip(entries, views):
-                    h = e["hash"]
-                    # v is None for fp-clean slots: their bytes never left
-                    # the device, and their chunk is already durable (parent
-                    # manifest or a previous lead's pre-write)
-                    if h in parent_hashes or h in written or v is None:
-                        continue
-                    blob = (SER.frame_chunk(v, self.compress)
-                            if self.compress else v)
-                    self.store.put_chunk(self.tier, self.prefix, h, blob,
-                                         replicas=self.replicas, force=True)
-                    written.add(h)
-                    cbytes[h] = len(blob)
-                    prewritten_n += 1
+            with TR.span("repro.ckpt.predump.hash", timed=True) as hash_span:
+                hashed, hashed_n = self._plans_to_leaves(plans)
+            with TR.span("repro.ckpt.predump.write", timed=True) as write_span:
+                leaves = {}
+                prewritten_n = 0
+                for _idx, name, _dtype, _shape, _nbytes, _slots in plans:
+                    entries, views, leaf_crc = hashed[name]
+                    leaves[name] = {"entries": entries, "crc32": leaf_crc}
+                    for e, v in zip(entries, views):
+                        h = e["hash"]
+                        # v is None for fp-clean slots: their bytes never left
+                        # the device, and their chunk is already durable (parent
+                        # manifest or a previous lead's pre-write)
+                        if h in parent_hashes or h in written or v is None:
+                            continue
+                        blob = (SER.frame_chunk(v, self.compress)
+                                if self.compress else v)
+                        self.store.put_chunk(self.tier, self.prefix, h, blob,
+                                             replicas=self.replicas, force=True)
+                        written.add(h)
+                        cbytes[h] = len(blob)
+                        prewritten_n += 1
             self._predump = {
                 "step": step, "chunk_bytes": self.chunk_bytes,
                 "leaves": leaves, "written": written, "markers": markers,
                 "cbytes": cbytes,
-                "hash_s": hash_s, "write_s": time.perf_counter() - t1,
+                "hash_s": hash_span.elapsed_s,
+                "write_s": write_span.elapsed_s,
                 "chunks_hashed": hashed_n,
                 "chunks_prewritten": prewritten_n,
                 "d2h_bytes": dstats["d2h_bytes"],
@@ -734,7 +740,7 @@ class CheckpointManager:
                 "d2h_s": dstats["d2h_s"]}
 
     def _save_delta(self, step: int, records, snap_s: float,
-                    extra_meta: Optional[dict]) -> dict:
+                    extra_meta: Optional[dict], sp) -> dict:
         """Chunk-plane save: every leaf is chunked/hashed/CRC'd concurrently
         (all chunks in flight across the hash engine's pool), then only
         chunks absent from the parent manifest are written to the dedup
@@ -754,7 +760,6 @@ class CheckpointManager:
         Per-phase wall times land in ``part["delta"]`` (``fp_s``/``hash_s``/
         ``diff_s``/``write_s`` and the step-visible ``stall_s``) so the
         bench measures, not infers."""
-        t_entry = time.perf_counter()
         mine = self._my_leaves(records)
         sdir = _step_dir(self.prefix, step)
         index_rel = f"{sdir}/shard_w{self.worker_id:05d}.chunks"
@@ -783,68 +788,68 @@ class CheckpointManager:
         # per-chunk nbytes, so a reshaped leaf can never alias).  The 32-bit
         # fp never NAMES a chunk — blake2b does — it only decides which
         # chunks need renaming.
-        t0 = time.perf_counter()
-        items = []
-        known: dict = {}
-        fps_by_name: dict = {}
-        for idx, name, arr in mine:
-            arr = np.asarray(arr)
-            items.append((name, arr))
-            ref_entries = None
-            if name in pre_leaves:
-                ref_entries = pre_leaves[name]["entries"]
-            elif name in parent_leaves:
-                ref_entries = parent_leaves[name]["chunks"]
-            if ref_entries is None and not self.fingerprint:
-                continue          # nothing to compare and nothing to stamp
-            fp = SER.fingerprint_chunks(SER.as_byte_view(arr),
-                                        self.chunk_bytes)
-            fps_by_name[name] = fp
-            if not ref_entries:
-                continue
-            kmap = {i: e for i, e in enumerate(ref_entries)
-                    if i < len(fp) and e.get("fp") is not None
-                    and int(fp[i]) == int(e["fp"])}
-            if kmap:
-                known[name] = kmap
-        fp_s = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        hashed, hstats = self.hash_engine.chunk_records(
-            items, self.chunk_bytes, known=known,
-            fps=fps_by_name if (self.fingerprint or fps_by_name) else None)
-        hash_s = time.perf_counter() - t0
-
-        t0 = time.perf_counter()
-        entries: list[dict] = []
-        new_views: dict[str, object] = {}     # hash -> zero-copy byte view
-        chunks_total = bytes_total = 0
-        for idx, name, arr in mine:
-            arr = np.asarray(arr)
-            chunks, views, leaf_crc = hashed[name]
-            nbytes = sum(c["nbytes"] for c in chunks)
-            fresh = 0
-            for c, v in zip(chunks, views):
-                chunks_total += 1
-                bytes_total += c["nbytes"]
-                if c["hash"] in parent_cbytes:
-                    c["cbytes"] = parent_cbytes[c["hash"]]
-                if c["hash"] in parent_hashes:
+        with TR.span("repro.ckpt.delta.fp", timed=True) as fp_span:
+            items = []
+            known: dict = {}
+            fps_by_name: dict = {}
+            for idx, name, arr in mine:
+                arr = np.asarray(arr)
+                items.append((name, arr))
+                ref_entries = None
+                if name in pre_leaves:
+                    ref_entries = pre_leaves[name]["entries"]
+                elif name in parent_leaves:
+                    ref_entries = parent_leaves[name]["chunks"]
+                if ref_entries is None and not self.fingerprint:
+                    continue          # nothing to compare and nothing to stamp
+                fp = SER.fingerprint_chunks(SER.as_byte_view(arr),
+                                            self.chunk_bytes)
+                fps_by_name[name] = fp
+                if not ref_entries:
                     continue
-                fresh += 1
-                # dedup at diff time: unchanged-since-parent chunks (the
-                # parent manifest is always in the GC keep set, so its
-                # chunks cannot be reaped under us) and duplicates within
-                # this save are never queued for writing
-                if c["hash"] not in new_views:
-                    new_views[c["hash"]] = v
-            entries.append({
-                "path": name, "index": idx, "crc32": leaf_crc,
-                "dtype": str(arr.dtype), "shape": list(arr.shape),
-                "nbytes": nbytes, "chunks": chunks,
-                "reused": not fresh,
-            })
-        diff_s = time.perf_counter() - t0
+                kmap = {i: e for i, e in enumerate(ref_entries)
+                        if i < len(fp) and e.get("fp") is not None
+                        and int(fp[i]) == int(e["fp"])}
+                if kmap:
+                    known[name] = kmap
+        fp_s = fp_span.elapsed_s
+
+        with TR.span("repro.ckpt.delta.hash", timed=True) as hash_span:
+            hashed, hstats = self.hash_engine.chunk_records(
+                items, self.chunk_bytes, known=known,
+                fps=fps_by_name if (self.fingerprint or fps_by_name) else None)
+        hash_s = hash_span.elapsed_s
+
+        with TR.span("repro.ckpt.delta.diff", timed=True) as diff_span:
+            entries: list[dict] = []
+            new_views: dict[str, object] = {}     # hash -> zero-copy byte view
+            chunks_total = bytes_total = 0
+            for idx, name, arr in mine:
+                arr = np.asarray(arr)
+                chunks, views, leaf_crc = hashed[name]
+                nbytes = sum(c["nbytes"] for c in chunks)
+                fresh = 0
+                for c, v in zip(chunks, views):
+                    chunks_total += 1
+                    bytes_total += c["nbytes"]
+                    if c["hash"] in parent_cbytes:
+                        c["cbytes"] = parent_cbytes[c["hash"]]
+                    if c["hash"] in parent_hashes:
+                        continue
+                    fresh += 1
+                    # dedup at diff time: unchanged-since-parent chunks (the
+                    # parent manifest is always in the GC keep set, so its
+                    # chunks cannot be reaped under us) and duplicates within
+                    # this save are never queued for writing
+                    if c["hash"] not in new_views:
+                        new_views[c["hash"]] = v
+                entries.append({
+                    "path": name, "index": idx, "crc32": leaf_crc,
+                    "dtype": str(arr.dtype), "shape": list(arr.shape),
+                    "nbytes": nbytes, "chunks": chunks,
+                    "reused": not fresh,
+                })
+        diff_s = diff_span.elapsed_s
         part = {
             "worker_id": self.worker_id,
             "num_workers": self.num_workers,
@@ -877,12 +882,11 @@ class CheckpointManager:
         }
         return self._finish_delta(step, part, entries, new_views,
                                   pre=pre, parent=parent,
-                                  snap_s=snap_s, t_entry=t_entry)
+                                  sp=sp)
 
     def _finish_delta(self, step: int, part: dict, entries: list,
                       new_views: dict, *, pre: Optional[dict],
-                      parent: Optional[dict], snap_s: float,
-                      t_entry: float) -> dict:
+                      parent: Optional[dict], sp) -> dict:
         """Shared write tail of the host (``_save_delta``) and device
         (``_save_delta_device``) delta paths: intent marker, chunk writes,
         single-worker orphan sweep, v3 index, wpart, marker teardown, and
@@ -919,98 +923,99 @@ class CheckpointManager:
                                        "worker": self.worker_id,
                                        "t": time.time()}).encode(),
                            replicas=1)
-            t1 = time.perf_counter()
-            written_b = written_c = predumped = cbytes_b = 0
-            cbytes_out: dict[str, int] = {}
-            for h, v in new_views.items():
-                if h in pre_written and self.store.exists(
-                        self.tier, chunk_rel(self.prefix, h)):
-                    predumped += 1
-                    if h in pre_cbytes:
-                        cbytes_out[h] = pre_cbytes[h]
-                    continue
-                if v is None:
-                    # device path, clean-since-pre-dump chunk: the bytes were
-                    # never gathered off the device because the pre-written
-                    # file existed during the sync phase.  Gone now means a
-                    # reap won the race (same TOCTOU family the force=True
-                    # note documents) — with no bytes in hand the only safe
-                    # move is to abort this save before any manifest names
-                    # the hash; the previous committed step stays restorable
-                    raise RuntimeError(
-                        f"pre-written chunk {h} disappeared before the "
-                        f"step-{step} write; aborting save (no manifest cut)")
-                # the frame wraps the STORED bytes only: h stays the blake2b
-                # of the raw view, so dedup/fingerprints are codec-blind
-                blob = (SER.frame_chunk(v, self.compress)
-                        if self.compress else v)
-                if self.store.put_chunk(self.tier, self.prefix, h, blob,
-                                        replicas=self.replicas, force=True):
-                    written_c += 1
-                    written_b += v.nbytes
-                    cbytes_out[h] = len(blob)
-                    cbytes_b += len(blob)
-            if self.compress and cbytes_out:
-                for e in entries:
-                    for c in e["chunks"]:
-                        if c["hash"] in cbytes_out:
-                            c["cbytes"] = cbytes_out[c["hash"]]
-            part["delta"]["chunks_written"] = written_c
-            part["delta"]["bytes_written"] = written_b
-            part["delta"]["cbytes_written"] = cbytes_b
-            part["delta"]["chunks_predumped"] = predumped
-            if pre_written and self.num_workers == 1:
-                # pre-dumped chunks the live state no longer contains are
-                # referenced by NO manifest ever — gc() walks manifests, so
-                # they would leak forever.  Single-worker only: with
-                # concurrent workers a same-content chunk could legitimately
-                # belong to another worker's in-flight save; those orphans
-                # are reclaimed by the coordinator-side sweep_orphan_chunks
-                # pass instead (gc() runs it, barriered on the in-flight
-                # intent markers).  The spare set mirrors gc()'s contract — a
-                # chunk stays while ANY kept manifest references it: content
-                # can recur from an older retained step whose hash the
-                # parent manifest does not carry, and a pre-write of that
-                # hash lands on the very file the old step still resolves
-                # through.
-                final = {c["hash"] for e in entries for c in e["chunks"]}
-                cands = pre_written - final - parent_hashes
-                keep_hashes: Optional[set] = set()
-                parent_step = parent["step"] if parent else None
-                if cands:          # fully-consumed pre-dump: no reads at all
-                    try:
-                        all_steps = self.steps()
-                        kept = (all_steps[-self.keep_last:] if self.keep_last
-                                else all_steps)
-                        for s in kept:
-                            if s != parent_step:
-                                keep_hashes |= manifest_chunk_hashes(
-                                    self.read_manifest(s))
-                    except (FileNotFoundError, ValueError, KeyError, OSError):
-                        # can't prove a chunk unreferenced: leak it (bounded,
-                        # reclaimed by a later sweep) rather than tear a
-                        # restorable step
-                        keep_hashes = None
-                if keep_hashes is not None:
-                    for h in sorted(cands - keep_hashes):
-                        self.store.delete_file(self.tier,
-                                               chunk_rel(self.prefix, h))
-            # the v3 index file is the format's on-disk artifact for tooling
-            # and disaster recovery (a manifest can be rebuilt from index
-            # files alone); the restore path reads the manifest, so one
-            # replica of this few-KB file is plenty
-            self.store.put(
-                self.tier, index_rel,
-                SER.write_chunk_index_bytes(entries, meta={"step": step},
-                                            chunk_bytes=self.chunk_bytes),
-                replicas=1)
+            with TR.span("repro.ckpt.delta.write", timed=True) as write_span:
+                written_b = written_c = predumped = cbytes_b = 0
+                cbytes_out: dict[str, int] = {}
+                for h, v in new_views.items():
+                    if h in pre_written and self.store.exists(
+                            self.tier, chunk_rel(self.prefix, h)):
+                        predumped += 1
+                        if h in pre_cbytes:
+                            cbytes_out[h] = pre_cbytes[h]
+                        continue
+                    if v is None:
+                        # device path, clean-since-pre-dump chunk: the bytes were
+                        # never gathered off the device because the pre-written
+                        # file existed during the sync phase.  Gone now means a
+                        # reap won the race (same TOCTOU family the force=True
+                        # note documents) — with no bytes in hand the only safe
+                        # move is to abort this save before any manifest names
+                        # the hash; the previous committed step stays restorable
+                        raise RuntimeError(
+                            f"pre-written chunk {h} disappeared before the "
+                            f"step-{step} write; aborting save (no manifest cut)")
+                    # the frame wraps the STORED bytes only: h stays the blake2b
+                    # of the raw view, so dedup/fingerprints are codec-blind
+                    blob = (SER.frame_chunk(v, self.compress)
+                            if self.compress else v)
+                    if self.store.put_chunk(self.tier, self.prefix, h, blob,
+                                            replicas=self.replicas, force=True):
+                        written_c += 1
+                        written_b += v.nbytes
+                        cbytes_out[h] = len(blob)
+                        cbytes_b += len(blob)
+                if self.compress and cbytes_out:
+                    for e in entries:
+                        for c in e["chunks"]:
+                            if c["hash"] in cbytes_out:
+                                c["cbytes"] = cbytes_out[c["hash"]]
+                part["delta"]["chunks_written"] = written_c
+                part["delta"]["bytes_written"] = written_b
+                part["delta"]["cbytes_written"] = cbytes_b
+                part["delta"]["chunks_predumped"] = predumped
+                if pre_written and self.num_workers == 1:
+                    # pre-dumped chunks the live state no longer contains are
+                    # referenced by NO manifest ever — gc() walks manifests, so
+                    # they would leak forever.  Single-worker only: with
+                    # concurrent workers a same-content chunk could legitimately
+                    # belong to another worker's in-flight save; those orphans
+                    # are reclaimed by the coordinator-side sweep_orphan_chunks
+                    # pass instead (gc() runs it, barriered on the in-flight
+                    # intent markers).  The spare set mirrors gc()'s contract — a
+                    # chunk stays while ANY kept manifest references it: content
+                    # can recur from an older retained step whose hash the
+                    # parent manifest does not carry, and a pre-write of that
+                    # hash lands on the very file the old step still resolves
+                    # through.
+                    final = {c["hash"] for e in entries for c in e["chunks"]}
+                    cands = pre_written - final - parent_hashes
+                    keep_hashes: Optional[set] = set()
+                    parent_step = parent["step"] if parent else None
+                    if cands:          # fully-consumed pre-dump: no reads at all
+                        try:
+                            all_steps = self.steps()
+                            kept = (all_steps[-self.keep_last:] if self.keep_last
+                                    else all_steps)
+                            for s in kept:
+                                if s != parent_step:
+                                    keep_hashes |= manifest_chunk_hashes(
+                                        self.read_manifest(s))
+                        except (FileNotFoundError, ValueError, KeyError, OSError):
+                            # can't prove a chunk unreferenced: leak it (bounded,
+                            # reclaimed by a later sweep) rather than tear a
+                            # restorable step
+                            keep_hashes = None
+                    if keep_hashes is not None:
+                        for h in sorted(cands - keep_hashes):
+                            self.store.delete_file(self.tier,
+                                                   chunk_rel(self.prefix, h))
+                # the v3 index file is the format's on-disk artifact for tooling
+                # and disaster recovery (a manifest can be rebuilt from index
+                # files alone); the restore path reads the manifest, so one
+                # replica of this few-KB file is plenty
+                self.store.put(
+                    self.tier, index_rel,
+                    SER.write_chunk_index_bytes(entries, meta={"step": step},
+                                                chunk_bytes=self.chunk_bytes),
+                    replicas=1)
             # write_s is final BEFORE the wpart is serialized, so the phase
             # timing actually reaches disk (the wpart put it excludes is a
             # few KB of JSON)
-            part["delta"]["write_s"] = time.perf_counter() - t1
-            self.store.put(
-                self.tier, f"{sdir}/wpart_{self.worker_id:05d}.json",
-                json.dumps(part).encode(), replicas=self.replicas)
+            part["delta"]["write_s"] = write_span.elapsed_s
+            with TR.span("repro.ckpt.save.wpart"):
+                self.store.put(
+                    self.tier, f"{sdir}/wpart_{self.worker_id:05d}.json",
+                    json.dumps(part).encode(), replicas=self.replicas)
             # markers come down only AFTER the wpart is durable: from here on
             # the sweep sees this save's chunks through the wpart's refs, so
             # the handoff leaves no window where they are unprotected.  The
@@ -1030,11 +1035,11 @@ class CheckpointManager:
         # insert during that json.dumps can tear the write; post-submit cost
         # on this thread is a queue append, so nothing visible is lost.
         if self._writer is not None:
-            part["delta"]["stall_s"] = snap_s + (time.perf_counter() - t_entry)
+            part["delta"]["stall_s"] = sp.lap_s()
             self._writer.submit(do_write)
         else:
             do_write()
-            part["delta"]["stall_s"] = snap_s + (time.perf_counter() - t_entry)
+            part["delta"]["stall_s"] = sp.lap_s()
         return part
 
     # -- device-resident dirty detection (delta + device_fp) ------------
@@ -1066,11 +1071,11 @@ class CheckpointManager:
         """
         from repro.kernels import ops as KOPS
 
-        t0 = time.perf_counter()
-        fps = KOPS.tree_chunk_fingerprints(
-            [(name, leaf) for _, name, leaf in mine], self.chunk_bytes,
-            impl=self.device_fp_impl)
-        fp_device_s = time.perf_counter() - t0
+        with TR.span("repro.ckpt.delta.fp", timed=True) as fp_span:
+            fps = KOPS.tree_chunk_fingerprints(
+                [(name, leaf) for _, name, leaf in mine], self.chunk_bytes,
+                impl=self.device_fp_impl)
+        fp_device_s = fp_span.elapsed_s
 
         cb = self.chunk_bytes
         d2h_bytes, d2h_s, clean = 0, 0.0, 0
@@ -1112,9 +1117,9 @@ class CheckpointManager:
                     b1 = min((b + 1) * cb, nbytes)
                     e0 = b0 // itemsize
                     e1 = -(-b1 // itemsize)
-                    t1 = time.perf_counter()
-                    seg = np.ascontiguousarray(np.asarray(flat[e0:e1]))
-                    d2h_s += time.perf_counter() - t1
+                    with TR.span("repro.ckpt.delta.d2h", timed=True) as d2h:
+                        seg = np.ascontiguousarray(np.asarray(flat[e0:e1]))
+                    d2h_s += d2h.elapsed_s
                     d2h_bytes += seg.nbytes
                     segb = memoryview(seg.view(np.uint8).reshape(-1))
                     off = b0 - e0 * itemsize
@@ -1164,7 +1169,7 @@ class CheckpointManager:
         return leaves, len(todo)
 
     def _save_delta_device(self, step: int, tree,
-                           extra_meta: Optional[dict]) -> dict:
+                           extra_meta: Optional[dict], sp) -> dict:
         """Delta save with dirty detection on the accelerator: the Pallas/
         jnp fingerprint pass runs over the LIVE device-resident leaves, and
         only fp-dirty chunk runs cross the device->host link — at low churn
@@ -1173,7 +1178,6 @@ class CheckpointManager:
         reuse the pre-dump/parent entries verbatim; pre-written-but-
         uncommitted hashes are existence-verified synchronously here and
         refetched from the device if a reap won the race."""
-        t_entry = time.perf_counter()
         from repro.utils.tree import flatten_with_names
 
         named = flatten_with_names(tree)
@@ -1204,40 +1208,40 @@ class CheckpointManager:
                 self.tier, chunk_rel(self.prefix, h))
 
         plans, dstats = self._device_scan(mine, refs_for, trust)
-        t0 = time.perf_counter()
-        leaves, hashed_n = self._plans_to_leaves(plans)
-        hash_s = time.perf_counter() - t0
+        with TR.span("repro.ckpt.delta.hash", timed=True) as hash_span:
+            leaves, hashed_n = self._plans_to_leaves(plans)
+        hash_s = hash_span.elapsed_s
 
-        t0 = time.perf_counter()
-        entries: list[dict] = []
-        new_views: dict[str, object] = {}
-        new_sizes: dict[str, int] = {}
-        chunks_total = bytes_total = 0
-        for idx, name, dtype, shape, nbytes, _slots in plans:
-            chunks, views, leaf_crc = leaves[name]
-            fresh = 0
-            for c, v in zip(chunks, views):
-                chunks_total += 1
-                bytes_total += c["nbytes"]
-                if c["hash"] in parent_cbytes:
-                    c["cbytes"] = parent_cbytes[c["hash"]]
-                if c["hash"] in parent_hashes:
-                    continue
-                fresh += 1
-                # keep a real view if ANY duplicate slot fetched one — the
-                # write loop can then repair a reaped pre-write instead of
-                # aborting on the None placeholder
-                if (c["hash"] not in new_views
-                        or (new_views[c["hash"]] is None and v is not None)):
-                    new_views[c["hash"]] = v
-                    new_sizes[c["hash"]] = c["nbytes"]
-            entries.append({
-                "path": name, "index": idx, "crc32": leaf_crc,
-                "dtype": dtype, "shape": shape,
-                "nbytes": nbytes, "chunks": chunks,
-                "reused": not fresh,
-            })
-        diff_s = time.perf_counter() - t0
+        with TR.span("repro.ckpt.delta.diff", timed=True) as diff_span:
+            entries: list[dict] = []
+            new_views: dict[str, object] = {}
+            new_sizes: dict[str, int] = {}
+            chunks_total = bytes_total = 0
+            for idx, name, dtype, shape, nbytes, _slots in plans:
+                chunks, views, leaf_crc = leaves[name]
+                fresh = 0
+                for c, v in zip(chunks, views):
+                    chunks_total += 1
+                    bytes_total += c["nbytes"]
+                    if c["hash"] in parent_cbytes:
+                        c["cbytes"] = parent_cbytes[c["hash"]]
+                    if c["hash"] in parent_hashes:
+                        continue
+                    fresh += 1
+                    # keep a real view if ANY duplicate slot fetched one — the
+                    # write loop can then repair a reaped pre-write instead of
+                    # aborting on the None placeholder
+                    if (c["hash"] not in new_views
+                            or (new_views[c["hash"]] is None and v is not None)):
+                        new_views[c["hash"]] = v
+                        new_sizes[c["hash"]] = c["nbytes"]
+                entries.append({
+                    "path": name, "index": idx, "crc32": leaf_crc,
+                    "dtype": dtype, "shape": shape,
+                    "nbytes": nbytes, "chunks": chunks,
+                    "reused": not fresh,
+                })
+        diff_s = diff_span.elapsed_s
         part = {
             "worker_id": self.worker_id,
             "num_workers": self.num_workers,
@@ -1266,7 +1270,7 @@ class CheckpointManager:
         }
         return self._finish_delta(step, part, entries, new_views,
                                   pre=pre, parent=parent,
-                                  snap_s=0.0, t_entry=t_entry)
+                                  sp=sp)
 
     def wait_writes(self, timeout: Optional[float] = None) -> None:
         if self._writer is not None:
@@ -1297,55 +1301,60 @@ class CheckpointManager:
     def commit(self, step: int, *, num_workers: Optional[int] = None,
                extra_meta: Optional[dict] = None) -> dict:
         """Coordinator-side: verify all worker parts exist, write MANIFEST last."""
-        self.wait_writes()
-        nw = num_workers or self.num_workers
-        sdir = _step_dir(self.prefix, step)
-        leaves = []
-        meta: dict = {}
-        for w in range(nw):
-            raw = self.store.get(self.tier, f"{sdir}/wpart_{w:05d}.json")
-            part = json.loads(raw.decode())
-            leaves.extend(part["leaves"])
-            meta.update(part.get("meta") or {})   # worker metas merge (w0 first)
-        leaves.sort(key=lambda e: e["index"])
-        meta.update(extra_meta or {})
-        manifest = {
-            "step": step,
-            "num_workers": nw,
-            "leaves": leaves,
-            "committed_at": time.time(),
-            "meta": meta,
-        }
-        if any("chunks" in e for e in leaves):
-            # manifest v2: record the baseline+delta chain.  The manifest is
-            # SELF-CONTAINED (it lists every chunk each leaf needs, not just
-            # the new ones), so the chain is provenance/observability — GC
-            # and restore never have to walk ancestors.  rebase_every bounds
-            # the reported chain; content addressing makes the rebaseline
-            # free (unchanged chunks are never re-written).
-            manifest["manifest_version"] = 2
-            parent = self._parent_manifest()
-            chain, baseline, parent_step = [step], step, None
-            if parent is not None and is_chunked_manifest(parent):
-                pdelta = parent.get("delta") or {}
-                pchain = pdelta.get("chain") or [parent["step"]]
-                if len(pchain) < self.rebase_every:
-                    parent_step = parent["step"]
-                    chain = pchain + [step]
-                    baseline = pdelta.get("baseline", parent["step"])
-            manifest["delta"] = {
-                "baseline": baseline, "parent": parent_step, "chain": chain,
-                "chunk_bytes": self.chunk_bytes,
-            }
-        self.store.put(self.tier, f"{sdir}/MANIFEST.json",
-                       json.dumps(manifest).encode(), replicas=self.replicas)
-        self._prev_manifest = manifest
-        self.gc()
-        if self.promote == "eager":
-            # keep the node-local cache tracking the newest commit so a
-            # restart on this node never touches the shared tier
-            self._schedule_promotion(manifest)
-        return manifest
+        with TR.span("repro.ckpt.commit", cpu=True, step=step):
+            with TR.span("repro.ckpt.commit.wait_writes"):
+                self.wait_writes()
+            nw = num_workers or self.num_workers
+            sdir = _step_dir(self.prefix, step)
+            leaves = []
+            meta: dict = {}
+            with TR.span("repro.ckpt.commit.read_parts"):
+                for w in range(nw):
+                    raw = self.store.get(self.tier, f"{sdir}/wpart_{w:05d}.json")
+                    part = json.loads(raw.decode())
+                    leaves.extend(part["leaves"])
+                    meta.update(part.get("meta") or {})   # worker metas merge (w0 first)
+            with TR.span("repro.ckpt.commit.manifest"):
+                leaves.sort(key=lambda e: e["index"])
+                meta.update(extra_meta or {})
+                manifest = {
+                    "step": step,
+                    "num_workers": nw,
+                    "leaves": leaves,
+                    "committed_at": time.time(),
+                    "meta": meta,
+                }
+                if any("chunks" in e for e in leaves):
+                    # manifest v2: record the baseline+delta chain.  The manifest is
+                    # SELF-CONTAINED (it lists every chunk each leaf needs, not just
+                    # the new ones), so the chain is provenance/observability — GC
+                    # and restore never have to walk ancestors.  rebase_every bounds
+                    # the reported chain; content addressing makes the rebaseline
+                    # free (unchanged chunks are never re-written).
+                    manifest["manifest_version"] = 2
+                    parent = self._parent_manifest()
+                    chain, baseline, parent_step = [step], step, None
+                    if parent is not None and is_chunked_manifest(parent):
+                        pdelta = parent.get("delta") or {}
+                        pchain = pdelta.get("chain") or [parent["step"]]
+                        if len(pchain) < self.rebase_every:
+                            parent_step = parent["step"]
+                            chain = pchain + [step]
+                            baseline = pdelta.get("baseline", parent["step"])
+                    manifest["delta"] = {
+                        "baseline": baseline, "parent": parent_step, "chain": chain,
+                        "chunk_bytes": self.chunk_bytes,
+                    }
+                self.store.put(self.tier, f"{sdir}/MANIFEST.json",
+                               json.dumps(manifest).encode(), replicas=self.replicas)
+            self._prev_manifest = manifest
+            with TR.span("repro.ckpt.commit.gc"):
+                self.gc()
+            if self.promote == "eager":
+                # keep the node-local cache tracking the newest commit so a
+                # restart on this node never touches the shared tier
+                self._schedule_promotion(manifest)
+            return manifest
 
     # ------------------------------------------------------------------
     def steps(self) -> list[int]:
@@ -1418,12 +1427,13 @@ class CheckpointManager:
         by_file = self._by_file(manifest)
         if self.restore_workers == 1:
             named: dict[str, np.ndarray] = {}
-            for rel, ents in by_file.items():
-                tensors, _ = self.store.read_shard_leaves(
-                    tier, rel, [e["path"] for e in ents],
-                    expect_crcs={e["path"]: e["crc32"] for e in ents})
-                for e in ents:
-                    named[e["path"]] = tensors[e["path"]]
+            with TR.span("repro.restore.run"):
+                for rel, ents in by_file.items():
+                    tensors, _ = self.store.read_shard_leaves(
+                        tier, rel, [e["path"] for e in ents],
+                        expect_crcs={e["path"]: e["crc32"] for e in ents})
+                    for e in ents:
+                        named[e["path"]] = tensors[e["path"]]
             return named, {"mode": "serial", "tier": tier,
                            "files": len(by_file), "workers": 1}
         engine = self._engine()
@@ -1485,7 +1495,19 @@ class CheckpointManager:
         full on the local tier, ...) suppress the advertisement but never
         fail the restore.
         """
-        all_steps = self.steps()
+        with TR.span("repro.ckpt.restore", cpu=True) as sp:
+            tree, manifest = self._restore(template, step, sources, promote,
+                                           follower_cache)
+            st = self.last_restore_stats
+            sp.set(step=manifest.get("step"), mode=st["mode"],
+                   bytes_read=st["bytes_read"],
+                   bytes_by_tier=dict(st["bytes_by_tier"]), tasks=st["tasks"],
+                   replica_fallbacks=st["replica_fallbacks"])
+        return tree, manifest
+
+    def _restore(self, template, step, sources, promote, follower_cache):
+        with TR.span("repro.ckpt.restore.list"):
+            all_steps = self.steps()
         if not all_steps:
             raise FileNotFoundError("no committed checkpoint found")
         step = all_steps[-1] if step is None else step
@@ -1508,7 +1530,8 @@ class CheckpointManager:
                 if got is not None:
                     named, manifest, stats = got
             if named is None:
-                manifest = self.read_manifest(step)
+                with TR.span("repro.ckpt.restore.manifest"):
+                    manifest = self.read_manifest(step)
                 if (is_chunked_manifest(manifest)
                         and self.promote_tier != self.tier):
                     # the node's own — possibly STALE — promoted cache joins
@@ -1530,7 +1553,8 @@ class CheckpointManager:
             if not sources:
                 raise ValueError("sources must be 'auto' or a non-empty "
                                  "tier list")
-            manifest = self.read_manifest(step)
+            with TR.span("repro.ckpt.restore.manifest"):
+                manifest = self.read_manifest(step)
             if is_chunked_manifest(manifest):
                 named, stats = self._restore_chunked(sources, manifest,
                                                      tee=tee)
@@ -1544,7 +1568,8 @@ class CheckpointManager:
                          **st.as_dict()}
             if mutate:
                 self._schedule_promotion(manifest)
-        tree = SER.restore_tree(template, named)
+        with TR.span("repro.ckpt.restore.assemble"):
+            tree = SER.restore_tree(template, named)
         self._prev_manifest = manifest
         self.last_restore_stats = self._finalize_stats(stats, manifest)
         if follower is not None:

@@ -47,6 +47,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import time
 import zlib
 from concurrent.futures import ThreadPoolExecutor
 from pathlib import Path
@@ -56,6 +57,7 @@ import numpy as np
 
 from repro.checkpoint import serialization as SER
 from repro.checkpoint.store import chunk_rel, is_peer_tier
+from repro.utils import tracing as TR
 from repro.utils.env import env_positive_int
 
 DEFAULT_SPLIT_BYTES = 32 << 20      # target max payload bytes per range task
@@ -221,7 +223,16 @@ class ParallelRestorer:
         start = run[0]["offset"]
         return start, run[-1]["offset"] + run[-1]["nbytes"] - start
 
-    def _exec_task(self, task: _RangeTask):
+    def _exec_task(self, task: _RangeTask, parent=None):
+        with TR.span("repro.restore.task", parent=parent,
+                     bytes=task.nbytes) as sp:
+            io = _IoClock(self.store)
+            try:
+                return self._exec_range_task(task, io)
+            finally:
+                io.report(sp)
+
+    def _exec_range_task(self, task: _RangeTask, io: "_IoClock"):
         """One submission with fallback down the (tier, path) source chain;
         returns the task's leaves plus (bytes_read, fallback_count, tier).
 
@@ -238,7 +249,7 @@ class ParallelRestorer:
                 with self.store.tier_slots(tier):
                     if len(task.runs) > 1:
                         spans = [self._run_span(r) for r in task.runs]
-                        got = self.store.pread_batch(
+                        got = io.pread_batch(
                             tier, [(p, s, n) for s, n in spans])
                         nbytes = 0
                         for run, (start, _n), blob in zip(task.runs, spans,
@@ -251,7 +262,7 @@ class ParallelRestorer:
                                 run, out)
                     else:
                         nbytes = SER.read_run(
-                            lambda off, n: self.store.pread(tier, p, off, n),
+                            lambda off, n: io.pread(tier, p, off, n),
                             task.runs[0], out)
                 return out, nbytes, i, tier
             except (SER.ChecksumError, OSError, ValueError) as e:
@@ -324,39 +335,42 @@ class ParallelRestorer:
 
         with ThreadPoolExecutor(max_workers=workers,
                                 thread_name_prefix="ckpt-restore") as pool:
-            ordered = list(pool.map(locate,
-                                    (works[d] for d in sorted(works))))
-            # batch by primary source so one task streams from one tier;
-            # cap at split_bytes so a large delta still fans out
-            groups: dict[str, list[_ChunkWork]] = {}
-            for w in ordered:
-                first = next((t for t in srcs if w.by_tier.get(t)), "")
-                groups.setdefault(first, []).append(w)
-            tasks: list[list[_ChunkWork]] = []
-            for _, ws in sorted(groups.items()):
-                cur: list[_ChunkWork] = []
-                cur_bytes = 0
-                for w in ws:
-                    if cur and cur_bytes + w.nbytes > self.split_bytes:
+            with TR.span("repro.restore.plan"):
+                ordered = list(pool.map(locate,
+                                        (works[d] for d in sorted(works))))
+                # batch by primary source so one task streams from one tier;
+                # cap at split_bytes so a large delta still fans out
+                groups: dict[str, list[_ChunkWork]] = {}
+                for w in ordered:
+                    first = next((t for t in srcs if w.by_tier.get(t)), "")
+                    groups.setdefault(first, []).append(w)
+                tasks: list[list[_ChunkWork]] = []
+                for _, ws in sorted(groups.items()):
+                    cur: list[_ChunkWork] = []
+                    cur_bytes = 0
+                    for w in ws:
+                        if cur and cur_bytes + w.nbytes > self.split_bytes:
+                            tasks.append(cur)
+                            cur, cur_bytes = [], 0
+                        cur.append(w)
+                        cur_bytes += w.nbytes
+                    if cur:
                         tasks.append(cur)
-                        cur, cur_bytes = [], 0
-                    cur.append(w)
-                    cur_bytes += w.nbytes
-                if cur:
-                    tasks.append(cur)
-            tasks.sort(key=lambda ws: sum(w.nbytes for w in ws),
-                       reverse=True)                    # LPT order
+                tasks.sort(key=lambda ws: sum(w.nbytes for w in ws),
+                           reverse=True)                    # LPT order
             stats.tasks = len(tasks)
-            futures = [pool.submit(self._exec_chunk_task, srcs, j, ws,
-                                   buffers, prefix, tee)
-                       for j, ws in enumerate(tasks)]
-            for fut in futures:
-                by_tier, fallbacks = fut.result()
-                stats.replica_fallbacks += fallbacks
-                for tier, n in by_tier.items():
-                    stats.bytes_read += n
-                    stats.bytes_by_tier[tier] = (
-                        stats.bytes_by_tier.get(tier, 0) + n)
+            with TR.span("repro.restore.run"):
+                run = TR.current()
+                futures = [pool.submit(self._exec_chunk_task, srcs, j, ws,
+                                       buffers, prefix, tee, run)
+                           for j, ws in enumerate(tasks)]
+                for fut in futures:
+                    by_tier, fallbacks = fut.result()
+                    stats.replica_fallbacks += fallbacks
+                    for tier, n in by_tier.items():
+                        stats.bytes_read += n
+                        stats.bytes_by_tier[tier] = (
+                            stats.bytes_by_tier.get(tier, 0) + n)
         return self._finish_chunked(leaves, buffers, stats)
 
     def _chunk_done(self, w: _ChunkWork, blob: bytes, raw: bytes, tier: str,
@@ -374,7 +388,19 @@ class ParallelRestorer:
 
     def _exec_chunk_task(self, srcs: list[str], index: int,
                          ws: list[_ChunkWork], buffers: dict,
-                         prefix: str = "", tee=None):
+                         prefix: str = "", tee=None, parent=None):
+        with TR.span("repro.restore.task", parent=parent,
+                     bytes=sum(w.nbytes for w in ws)) as sp:
+            io = _IoClock(self.store)
+            try:
+                return self._fetch_chunks(srcs, index, ws, buffers, prefix,
+                                          tee, io)
+            finally:
+                io.report(sp)
+
+    def _fetch_chunks(self, srcs: list[str], index: int,
+                      ws: list[_ChunkWork], buffers: dict, prefix: str,
+                      tee, io: "_IoClock"):
         """Fetch one batch of chunks and scatter the verified bytes into the
         leaf buffers (disjoint regions, so no locking).
 
@@ -404,7 +430,7 @@ class ParallelRestorer:
             pending = unplaced
             for tier, members in sorted(groups.items()):
                 with self.store.tier_slots(tier):
-                    got = self.store.pread_batch(
+                    got = io.pread_batch(
                         tier, [(p, 0, None) for _, p in members])
                 for (w, _p), blob in zip(members, got):
                     raw = None
@@ -426,8 +452,7 @@ class ParallelRestorer:
             for i, (tier, p) in enumerate(chain):
                 try:
                     with self.store.tier_slots(tier):
-                        blob = self.store.pread(tier, p, 0,
-                                                os.stat(p).st_size)
+                        blob = io.pread(tier, p, 0, os.stat(p).st_size)
                     raw = SER.unframe_chunk(blob, w.nbytes, crc32=w.crc32)
                     break
                 except (SER.ChecksumError, OSError, ValueError) as e:
@@ -464,50 +489,53 @@ class ParallelRestorer:
         named: dict[str, np.ndarray] = {}
         with ThreadPoolExecutor(max_workers=workers,
                                 thread_name_prefix="ckpt-restore") as pool:
-            plans = list(pool.map(
-                lambda item: self._plan_shard(sources, item[1][0], item[1][1],
-                                              shard_index=item[0]),
-                enumerate(by_file.items())))
-            tasks = []
-            j = 0
-            for plan in plans:
-                runs = SER.coalesce_runs(plan.want,
-                                         max_run_bytes=self.split_bytes)
-                # pack runs into one submission each, up to io_batch ranges
-                # and split_bytes total — small scattered leaves share one
-                # vectored read, a split_bytes-sized run stays its own task
-                # so LPT granularity (and the straggler bound) is unchanged
-                packs: list[list[list[dict]]] = []
-                cur: list[list[dict]] = []
-                cur_bytes = 0
-                for run in runs:
-                    rb = sum(t["nbytes"] for t in run)
-                    if cur and (len(cur) >= self.io_batch
-                                or cur_bytes + rb > self.split_bytes):
+            with TR.span("repro.restore.plan"):
+                plans = list(pool.map(
+                    lambda item: self._plan_shard(sources, item[1][0], item[1][1],
+                                                  shard_index=item[0]),
+                    enumerate(by_file.items())))
+                tasks = []
+                j = 0
+                for plan in plans:
+                    runs = SER.coalesce_runs(plan.want,
+                                             max_run_bytes=self.split_bytes)
+                    # pack runs into one submission each, up to io_batch ranges
+                    # and split_bytes total — small scattered leaves share one
+                    # vectored read, a split_bytes-sized run stays its own task
+                    # so LPT granularity (and the straggler bound) is unchanged
+                    packs: list[list[list[dict]]] = []
+                    cur: list[list[dict]] = []
+                    cur_bytes = 0
+                    for run in runs:
+                        rb = sum(t["nbytes"] for t in run)
+                        if cur and (len(cur) >= self.io_batch
+                                    or cur_bytes + rb > self.split_bytes):
+                            packs.append(cur)
+                            cur, cur_bytes = [], 0
+                        cur.append(run)
+                        cur_bytes += rb
+                    if cur:
                         packs.append(cur)
-                        cur, cur_bytes = [], 0
-                    cur.append(run)
-                    cur_bytes += rb
-                if cur:
-                    packs.append(cur)
-                for pack in packs:
-                    chain = [(t, p)
-                             for t in _ordered_tiers(sources, plan.by_tier, j)
-                             for p in plan.by_tier[t]]
-                    tasks.append(_RangeTask(
-                        rel=plan.rel, sources=chain, runs=pack,
-                        nbytes=sum(t["nbytes"] for r in pack for t in r)))
-                    j += 1
-            tasks.sort(key=lambda t: t.nbytes, reverse=True)   # LPT order
+                    for pack in packs:
+                        chain = [(t, p)
+                                 for t in _ordered_tiers(sources, plan.by_tier, j)
+                                 for p in plan.by_tier[t]]
+                        tasks.append(_RangeTask(
+                            rel=plan.rel, sources=chain, runs=pack,
+                            nbytes=sum(t["nbytes"] for r in pack for t in r)))
+                        j += 1
+                tasks.sort(key=lambda t: t.nbytes, reverse=True)   # LPT order
             stats.tasks = len(tasks)
-            futures = [pool.submit(self._exec_task, t) for t in tasks]
-            for fut in futures:
-                out, nbytes, fallbacks, tier = fut.result()
-                named.update(out)
-                stats.bytes_read += nbytes
-                stats.replica_fallbacks += fallbacks
-                stats.bytes_by_tier[tier] = (
-                    stats.bytes_by_tier.get(tier, 0) + nbytes)
+            with TR.span("repro.restore.run"):
+                run = TR.current()
+                futures = [pool.submit(self._exec_task, t, run) for t in tasks]
+                for fut in futures:
+                    out, nbytes, fallbacks, tier = fut.result()
+                    named.update(out)
+                    stats.bytes_read += nbytes
+                    stats.replica_fallbacks += fallbacks
+                    stats.bytes_by_tier[tier] = (
+                        stats.bytes_by_tier.get(tier, 0) + nbytes)
         return named, stats
 
 
@@ -522,3 +550,30 @@ def _ordered_tiers(sources: list[str], by_tier: dict, index: int) -> list[str]:
     k = index % len(peers)
     rotated = iter(peers[k:] + peers[:k])
     return [next(rotated) if is_peer_tier(t) else t for t in avail]
+
+
+class _IoClock:
+    """A restore task's reads through the store.  It sums the thread's
+    seconds inside ``pread``/``pread_batch``, which ``report`` adds to
+    ``repro.restore.pread_s`` and to the task's span when the task ends."""
+
+    def __init__(self, store):
+        self.store = store
+        self.ns = 0
+
+    def pread(self, tier, path, offset, nbytes):
+        return self._timed(self.store.pread, tier, path, offset, nbytes)
+
+    def pread_batch(self, tier, requests):
+        return self._timed(self.store.pread_batch, tier, requests)
+
+    def _timed(self, fn, *args):
+        t0 = time.perf_counter_ns()
+        try:
+            return fn(*args)
+        finally:
+            self.ns += time.perf_counter_ns() - t0
+
+    def report(self, sp) -> None:
+        TR.count("repro.restore.pread_s", self.ns / 1e9)
+        sp.set(pread_s=self.ns / 1e9)

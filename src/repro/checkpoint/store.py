@@ -54,6 +54,7 @@ from typing import BinaryIO, Callable, Iterable, Optional
 
 from repro.checkpoint import io_backend as IOB
 from repro.checkpoint import serialization as SER
+from repro.utils import tracing as TR
 from repro.utils.atomic import atomic_write_bytes
 
 # tiers whose backing store is a real (cold/shared parallel) filesystem —
@@ -63,6 +64,17 @@ from repro.utils.atomic import atomic_write_bytes
 DIRECT_IO_TIERS = ("shared",)
 
 
+def _short_read(path, offset: int, got: int, nbytes: int) -> OSError:
+    """The error for a range that came back short, with the file's size: a
+    replica cut short by its writer reads as a size below the range's end."""
+    try:
+        size = os.stat(path).st_size
+    except OSError:
+        size = None
+    return OSError(f"short read {got}/{nbytes} at offset {offset} in {path} "
+                   f"(file size {size})")
+
+
 class _FanoutSink:
     """Write-once tee: chunks handed to ``write`` are streamed to every
     replica file by a dedicated kernel-writer thread each.
@@ -70,31 +82,38 @@ class _FanoutSink:
     Chunks are enqueued by reference (zero-copy for ``memoryview``s whose
     backing buffers outlive the ``put_stream`` call, which holds the source
     arrays).  Bounded queues give backpressure so a slow replica cannot make
-    the producer buffer the whole shard.
+    the producer buffer the whole shard.  ``wait_ns`` sums the time the
+    producer blocked on a full queue; while the tracer is on, each writer
+    thread is a ``repro.store.replica_write`` span under the producer's span.
     """
 
     _CLOSE = object()
 
     def __init__(self, paths: list[Path], queue_depth: int = 4):
         self.nbytes = 0
+        self.wait_ns = 0
         self._queues = [queue.Queue(maxsize=queue_depth) for _ in paths]
         self._errs: list[BaseException] = []
+        parent = TR.current()
         self._threads = [
-            threading.Thread(target=self._drain, args=(p, q), daemon=True,
-                             name=f"ckpt-fanout-{i}")
+            threading.Thread(target=self._drain, args=(p, q, parent),
+                             daemon=True, name=f"ckpt-fanout-{i}")
             for i, (p, q) in enumerate(zip(paths, self._queues))
         ]
         for t in self._threads:
             t.start()
 
-    def _drain(self, path: Path, q: queue.Queue) -> None:
+    def _drain(self, path: Path, q: queue.Queue, parent) -> None:
         try:
-            with open(path, "wb") as fp:
+            with TR.span("repro.store.replica_write", parent=parent) as sp, \
+                    open(path, "wb") as fp:
+                nbytes = 0
                 while True:
                     chunk = q.get()
                     if chunk is self._CLOSE:
+                        sp.set(bytes=nbytes)
                         return
-                    fp.write(chunk)
+                    nbytes += fp.write(chunk)
         except BaseException as e:  # noqa: BLE001 — re-raised on the producer
             self._errs.append(e)
             while q.get() is not self._CLOSE:   # unblock the producer
@@ -106,8 +125,10 @@ class _FanoutSink:
     def write(self, chunk) -> int:
         if self._errs:
             raise self._errs[0]
+        t0 = time.perf_counter_ns()
         for q in self._queues:
             q.put(chunk)
+        self.wait_ns += time.perf_counter_ns() - t0
         n = len(chunk) if not isinstance(chunk, memoryview) else chunk.nbytes
         self.nbytes += n
         return n
@@ -341,6 +362,7 @@ class TieredStore:
         self._simulate(tier, len(data))
         written = [self._rel_of(primary)]
         self._replicate(tier, primary, rel, chosen[1:], written)
+        TR.count("repro.store.bytes_written", len(data) * len(written), tier=tier)
         return written
 
     def put_stream(self, tier: str, rel: str,
@@ -358,26 +380,34 @@ class TieredStore:
         minus the read-back).  Atomic per replica (tmp + rename-all at the
         end, so no torn replica is ever visible).
         """
-        chosen = self._choose_nodes(tier, replicas)
-        tmps, finals = [], []
-        for nd in chosen:
-            p = nd / rel
-            p.parent.mkdir(parents=True, exist_ok=True)
-            tmps.append(p.with_suffix(p.suffix + ".tmp"))
-            finals.append(p)
-        sink = _FanoutSink(tmps)
-        try:
-            write_fn(sink)
-            sink.finish()
-        except BaseException:
-            sink.abort()
-            for t in tmps:
-                t.unlink(missing_ok=True)
-            raise
-        for tmp, final in zip(tmps, finals):
-            tmp.rename(final)
-            self._fd_invalidate(final)
-            self._simulate(tier, sink.nbytes)
+        with TR.span("repro.store.stream", tier=tier) as sp:
+            chosen = self._choose_nodes(tier, replicas)
+            tmps, finals = [], []
+            for nd in chosen:
+                p = nd / rel
+                p.parent.mkdir(parents=True, exist_ok=True)
+                tmps.append(p.with_suffix(p.suffix + ".tmp"))
+                finals.append(p)
+            sink = _FanoutSink(tmps)
+            try:
+                with TR.span("repro.store.serialize"):
+                    write_fn(sink)
+                with TR.span("repro.store.drain"):
+                    sink.finish()
+            except BaseException:
+                sink.abort()
+                for t in tmps:
+                    t.unlink(missing_ok=True)
+                raise
+            with TR.span("repro.store.publish"):
+                for tmp, final in zip(tmps, finals):
+                    tmp.rename(final)
+                    self._fd_invalidate(final)
+                    self._simulate(tier, sink.nbytes)
+            sp.set(bytes=sink.nbytes * len(finals))
+            TR.count("repro.store.fanout_wait_s", sink.wait_ns / 1e9)
+            TR.count("repro.store.bytes_written", sink.nbytes * len(finals),
+                     tier=tier)
         return [self._rel_of(p) for p in finals]
 
     # -- chunk plane ---------------------------------------------------
@@ -515,7 +545,10 @@ class TieredStore:
         """Positional read — the single choke point for all ranged I/O (tests
         wrap/override it to count bytes actually fetched).  Reuses one cached
         fd per replica file across a task's coalesced reads (``os.pread`` is
-        positional, so concurrent range tasks share the descriptor safely)."""
+        positional, so concurrent range tasks share the descriptor safely).
+        A read may return fewer bytes than asked without being at the end
+        of the file (POSIX allows it; network file systems do it), so it
+        reads on until the range is whole or the file ends."""
         if not hasattr(os, "pread"):            # non-POSIX fallback
             with open(path, "rb") as fp:
                 fp.seek(offset)
@@ -523,7 +556,13 @@ class TieredStore:
         path = Path(path)
         ent = self._fd_acquire(path)
         try:
-            return os.pread(ent.fd, nbytes, offset)
+            data = os.pread(ent.fd, nbytes, offset)
+            while len(data) < nbytes:
+                more = os.pread(ent.fd, nbytes - len(data), offset + len(data))
+                if not more:
+                    break
+                data += more
+            return data
         finally:
             self._fd_release(path, ent)
 
@@ -539,7 +578,7 @@ class TieredStore:
         read so a truncated replica triggers fallback, never silent loss."""
         data = self._pread(path, offset, nbytes)
         if len(data) != nbytes:
-            raise OSError(f"short read {len(data)}/{nbytes} in {path}")
+            raise _short_read(path, offset, len(data), nbytes)
         self._simulate(tier, nbytes)
         return data
 
@@ -606,8 +645,7 @@ class TieredStore:
                 try:
                     data = self._pread(path, offset, nbytes)
                     if len(data) != nbytes:
-                        raise OSError(
-                            f"short read {len(data)}/{nbytes} in {path}")
+                        raise _short_read(path, offset, len(data), nbytes)
                     results[i] = data
                 except OSError as e:
                     results[i] = e
@@ -629,8 +667,7 @@ class TieredStore:
                 if isinstance(data, Exception):
                     results[i] = data
                 elif len(data) != nbytes:
-                    results[i] = OSError(
-                        f"short read {len(data)}/{nbytes} in {path}")
+                    results[i] = _short_read(path, offset, len(data), nbytes)
                 else:
                     results[i] = data
         ok_bytes = sum(len(r) for r in results if isinstance(r, bytes))

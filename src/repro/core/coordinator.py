@@ -199,7 +199,7 @@ class CheckpointCoordinator:
         # the round forms one consistent named cut.
         label = step if step >= 0 else rid
         rec = {"round": rid, "step": label, "reason": reason,
-               "workers": sorted(workers), "t_start": time.time()}
+               "workers": sorted(workers)}
         if not workers:
             rec.update(ok=False, error="no workers")
             self._history.append(rec)
@@ -209,11 +209,9 @@ class CheckpointCoordinator:
         if not self._await_acks(P.READY, workers, self.straggler_timeout):
             self._abort(rid, workers, rec, "quiesce barrier failed")
             return rec
-        rec["t_quiesced"] = time.time()
         if not self._await_acks(P.WRITTEN, workers, self.straggler_timeout):
             self._abort(rid, workers, rec, "write barrier failed")
             return rec
-        rec["t_written"] = time.time()
         try:
             manifest = (self.commit_fn(label, num_workers=len(workers))
                         if self.commit_fn else {"step": label})
@@ -221,8 +219,7 @@ class CheckpointCoordinator:
             self._abort(rid, workers, rec, f"commit failed: {e}")
             return rec
         self._broadcast(P.msg(P.COMMIT, round=rid, step=step), sorted(workers))
-        rec.update(ok=True, t_commit=time.time(),
-                   manifest_step=manifest.get("step"),
+        rec.update(ok=True, manifest_step=manifest.get("step"),
                    written_meta=self._written_meta)
         self._history.append(rec)
         self.log(f"checkpoint round {rid} (step {step}) committed")
@@ -230,7 +227,7 @@ class CheckpointCoordinator:
 
     def _abort(self, rid, workers, rec, why):
         self._broadcast(P.msg(P.ABORT, round=rid, reason=why), sorted(workers))
-        rec.update(ok=False, error=why, t_abort=time.time())
+        rec.update(ok=False, error=why)
         self._history.append(rec)
         self.log(f"checkpoint round {rid} ABORTED: {why}")
 

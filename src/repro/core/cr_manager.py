@@ -26,6 +26,7 @@ from repro.core.requeue import RequeueFile, WalltimeTracker, detect_node
 from repro.core.signals import SignalTrap
 from repro.core.virtualization import fetch_tree, place_tree
 from repro.core.worker import InlineCoordinator
+from repro.utils import tracing as TR
 
 
 class CRManager:
@@ -69,6 +70,11 @@ class CRManager:
     def restore_or_init(self, init_fn, templates: dict, axes: Optional[dict] = None):
         """templates: {"state": host-template pytree}.  Returns
         (device_state, manifest_meta|None, start_step)."""
+        TR.follow_profiler()
+        with TR.span("repro.cr.restore_or_init", cpu=True):
+            return self._restore_or_init(init_fn, templates, axes)
+
+    def _restore_or_init(self, init_fn, templates: dict, axes: Optional[dict]):
         try:
             host_state, manifest = self.ckpt.restore(templates["state"])
         except FileNotFoundError:
@@ -85,7 +91,8 @@ class CRManager:
                      f"tasks={stats.get('tasks', stats.get('files'))}")
         meta = manifest.get("meta", {})
         if meta.get("run_manifest"):
-            verify_manifest(meta["run_manifest"], cfg=self.cfg, log=self.log)
+            with TR.span("repro.cr.verify_manifest"):
+                verify_manifest(meta["run_manifest"], cfg=self.cfg, log=self.log)
         state = place_tree(host_state, axes["state"] if axes else None,
                            self.rules) if axes else place_tree(host_state, None, None)
         start_step = int(meta.get("next_step", manifest["step"] + 1))
@@ -133,6 +140,14 @@ class CRManager:
     def step_boundary(self, step: int, state_fn, *,
                       extra_meta: Optional[dict] = None) -> str:
         """Returns 'exit' | 'checkpointed' | 'continue'."""
+        TR.follow_profiler()
+        with TR.span("repro.cr.step_boundary", step=step) as sp:
+            action = self._step_boundary(step, state_fn, extra_meta)
+            sp.set(save=action != "continue")
+        return action
+
+    def _step_boundary(self, step: int, state_fn,
+                       extra_meta: Optional[dict]) -> str:
         reason = self.exit_reason()
         if reason is not None:
             self.log(f"[cr] exit condition at step {step}: {reason}")

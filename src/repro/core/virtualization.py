@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.parallel.mesh_rules import Rules
+from repro.utils import tracing as TR
 
 tree_map = jax.tree_util.tree_map
 
@@ -25,20 +26,32 @@ def place_tree(host_tree, axes_tree, rules: Optional[Rules]):
     """device_put every leaf with the sharding derived from its logical axes.
 
     ``rules=None`` places on the default device (single-device restore)."""
-    if rules is None:
-        return tree_map(jax.device_put, host_tree)
-
-    flat_h, treedef = jax.tree_util.tree_flatten(host_tree)
-    flat_a = treedef.flatten_up_to(axes_tree)
-    out = []
-    for arr, axes in zip(flat_h, flat_a):
-        arr = np.asarray(arr)
-        sh = rules.sharding(axes, arr.shape)
-        out.append(jax.device_put(arr, sh))
-    return jax.tree_util.tree_unflatten(treedef, out)
+    with TR.span("repro.place_tree") as sp:
+        if rules is None:
+            out = tree_map(jax.device_put, host_tree)
+        else:
+            flat_h, treedef = jax.tree_util.tree_flatten(host_tree)
+            flat_a = treedef.flatten_up_to(axes_tree)
+            leaves = []
+            for arr, axes in zip(flat_h, flat_a):
+                arr = np.asarray(arr)
+                sh = rules.sharding(axes, arr.shape)
+                leaves.append(jax.device_put(arr, sh))
+            out = jax.tree_util.tree_unflatten(treedef, leaves)
+        if TR.enabled():
+            sp.set(bytes=_nbytes(out))
+    return out
 
 
 def fetch_tree(device_tree):
     """Device pytree -> host (numpy) pytree; works for any sharding because
     jax gathers fully-addressable arrays transparently."""
-    return tree_map(lambda x: np.asarray(jax.device_get(x)), device_tree)
+    with TR.span("repro.fetch_tree") as sp:
+        out = tree_map(lambda x: np.asarray(jax.device_get(x)), device_tree)
+        if TR.enabled():
+            sp.set(bytes=_nbytes(out))
+    return out
+
+
+def _nbytes(tree) -> int:
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(tree))

@@ -22,6 +22,7 @@ import time
 from typing import Callable, Optional
 
 from repro.core import protocol as P
+from repro.utils import tracing as TR
 
 
 class CkptClient:
@@ -158,11 +159,13 @@ class InlineCoordinator:
         req, self._pending = self._pending, None
         if req is None:
             return None
-        t0 = time.time()
-        save_fn(step)
-        manifest = self.commit_fn(step, num_workers=1) if self.commit_fn else {}
+        with TR.span("repro.cr.checkpoint", timed=True,
+                     reason=req["reason"]) as sp:
+            save_fn(step)
+            manifest = (self.commit_fn(step, num_workers=1)
+                        if self.commit_fn else {})
         rec = {"type": P.COMMIT, "step": step, "reason": req["reason"],
-               "duration_s": time.time() - t0,
+               "duration_s": sp.elapsed_s,
                "manifest_step": manifest.get("step")}
         self.history.append(rec)
         return rec

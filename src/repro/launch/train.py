@@ -42,6 +42,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.optim import adamw
 from repro.parallel.mesh_rules import Rules
 from repro.train import step as TS
+from repro.utils import tracing as TR
 
 REQUEUE_EXIT = 85
 
@@ -139,6 +140,9 @@ def build_argparser():
     ap.add_argument("--metrics-out", default=None)
     ap.add_argument("--step-sleep", type=float, default=0.0,
                     help="artificial per-step delay (benchmark pacing)")
+    ap.add_argument("--trace-out", default=None,
+                    help="record the program's spans and counters and write "
+                         "them here as JSON when the attempt ends")
     return ap
 
 
@@ -174,6 +178,8 @@ def main(argv=None) -> int:
     # to the C/R loop itself.
     trap = SignalTrap()
     trap.__enter__()
+    if args.trace_out:
+        TR.enable()
     configure_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
@@ -255,10 +261,10 @@ def main(argv=None) -> int:
         # template for restore: abstract state (host arrays will be placed in)
         templates = {"state": TS.abstract_train_state(cfg, oc)}
         axes = {"state": TS.state_logical_axes(cfg)}
-        t0 = time.perf_counter()
-        state, meta, start_step = crm.restore_or_init(init_fn, templates, axes)
-        jax.block_until_ready(state)
-        state_ready_s = time.perf_counter() - t0
+        with TR.span("repro.train.state_ready", timed=True) as ready:
+            state, meta, start_step = crm.restore_or_init(init_fn, templates, axes)
+            jax.block_until_ready(state)
+        state_ready_s = ready.elapsed_s
         if meta is not None and "data_state" in meta:
             pipe.restore(PipelineState.from_dict(meta["data_state"]))
 
@@ -267,10 +273,10 @@ def main(argv=None) -> int:
         step = start_step
         for step in range(start_step, args.steps):
             batch = next(pipe)
-            t0 = time.perf_counter()
-            state, metrics = jitted(state, batch)
-            loss = float(metrics["loss"])       # waits for the step
-            step_s = time.perf_counter() - t0
+            with TR.span("repro.train.step", timed=True, step=step) as timed:
+                state, metrics = jitted(state, batch)
+                loss = float(metrics["loss"])   # waits for the step
+            step_s = timed.elapsed_s
             if args.step_sleep:
                 time.sleep(args.step_sleep)
             metrics_log.append({"step": step, "loss": loss,
@@ -299,6 +305,8 @@ def main(argv=None) -> int:
             run_summary(state_ready_s, metrics_log, crm.events)), flush=True)
     finally:
         trap.__exit__(None, None, None)
+        if args.trace_out:
+            TR.dump(args.trace_out)
     return exit_code
 
 

@@ -241,6 +241,37 @@ def test_get_range(tmp_path):
         store.get_range("shared", "f.bin", 8, 100)
 
 
+@pytest.mark.parametrize("how", ["pread", "pread_batch"])
+def test_partial_reads_still_give_the_whole_range(tmp_path, monkeypatch, how):
+    """A positional read may return fewer bytes than asked short of the
+    file's end (network file systems do); the store reads on."""
+    import os
+
+    blob = bytes(range(256)) * 40
+    store = TieredStore(tmp_path)
+    store.direct_io = False
+    store.put("shared", "f.bin", blob, replicas=1)
+    (p,) = store.replica_paths("shared", "f.bin")
+    real_pread, real_preadv = os.pread, os.preadv
+    monkeypatch.setattr(os, "pread", lambda fd, n, off: real_pread(fd, min(n, 7), off))
+    monkeypatch.setattr(os, "preadv", lambda fd, bufs, off:
+                        real_preadv(fd, [memoryview(bufs[0])[:7]], off))
+    ranges = [(3, 5000), (5003, 100), (9000, 1240)]
+    if how == "pread":
+        got = [store.pread("shared", p, off, n) for off, n in ranges]
+    else:
+        got = store.pread_batch("shared", [(p, off, n) for off, n in ranges])
+    assert got == [blob[off:off + n] for off, n in ranges]
+
+
+def test_short_read_past_the_end_names_the_file_size(tmp_path):
+    store = TieredStore(tmp_path)
+    store.put("shared", "f.bin", b"0123456789", replicas=1)
+    (p,) = store.replica_paths("shared", "f.bin")
+    with pytest.raises(OSError, match=r"short read 2/100 at offset 8 .*\(file size 10\)"):
+        store.pread("shared", p, 8, 100)
+
+
 def test_async_writer_bounds_inflight_tasks():
     import threading as th
 
